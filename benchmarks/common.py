@@ -28,6 +28,7 @@ if "jax" not in sys.modules:
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (HCRACConfig, MechanismConfig, SimConfig, simulate,
                         weighted_speedup)
 from repro.core.traces import (WORKLOADS, multicore_batch, random_mixes,
@@ -36,6 +37,10 @@ from repro.experiment import Experiment
 from repro.experiment.results import Results
 
 QUICK = os.environ.get("REPRO_BENCH_QUICK", "0") == "1"
+
+# persistent compile cache for every benchmark process (JAX's own
+# JAX_COMPILATION_CACHE_DIR when set, else the in-checkout default)
+compile_cache.enable()
 
 #: the repo root — every ``BENCH_*.json`` artifact lands here regardless
 #: of the CWD the driver was invoked from (the artifacts are part of the

@@ -1,7 +1,8 @@
 """Streaming mega-sweep engine characterization (DESIGN.md §13).
 
-Two arms per grid size, each in its OWN subprocess so ``ru_maxrss``
-isolates the arm's true peak host memory:
+Two arms per grid size, both in this process (a device belongs to one
+process, and ``benchmarks/run.py`` has already used it when it calls
+this module):
 
 * ``full``      — the materialized object-cell path, ``pipeline_depth=0``
   (the pre-§13 blocking serial loop): per-point stats dicts, host-side
@@ -11,13 +12,17 @@ isolates the arm's true peak host memory:
   the host only ever holds ``[chunk, n_deps]`` integer columns and the
   O(grid × n_metrics) float arrays.
 
-The parent compares the two arms' metric arrays bitwise (the §13 parity
-claim, at benchmark scale), derives points/sec and peak-RSS per arm,
-and asserts the headline: streamed+pipelined ≥ 1.2× points/sec over the
-blocking materialized path at the 10⁵-point size (full runs only —
-REPRO_BENCH_QUICK shrinks the grid below where the ratio is stable and
-only smoke-checks parity + memory).  Each arm also proves the one-
-compilation fact for its ~200 chunk launches.
+Each arm runs twice: once timed, with nothing instrumented, and once
+under ``tracemalloc`` for its peak host allocation (Python objects and
+numpy buffers, reset per arm — so one arm's leftovers never count
+against the next, which a process-wide RSS peak could not promise).
+The two arms' metric arrays are compared bitwise (the §13 parity claim,
+at benchmark scale), and the headline is asserted: streamed+pipelined
+≥ 1.2× points/sec over the blocking materialized path at the
+10⁵-point size (full runs only — REPRO_BENCH_QUICK shrinks the grid
+below where the ratio is stable and only smoke-checks parity +
+memory).  Each arm's ~200 chunk launches ride one compilation, counted
+over all of that arm's runs.
 
 Artifact: ``BENCH_megasweep.json`` (flat scalars so the trajectory
 recorder in ``benchmarks/run.py`` can pick them up).
@@ -27,11 +32,12 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import tempfile
+import tracemalloc
 
 import numpy as np
+
+from benchmarks import common as C
 
 #: metrics every arm materializes; the streamed arm lowers exactly their
 #: integer ingredients on device (metrics registry, DESIGN.md §13)
@@ -43,7 +49,6 @@ N_REQ = 16  # short per-point streams: launch economics dominate
 
 
 def _experiment(mode: str, n_points: int):
-    from benchmarks import common as C
     from repro.core.traces import single_core_batch
     from repro.experiment import Experiment
     from repro.experiment.spec import AXIS_BUILDERS, register_axis
@@ -70,91 +75,77 @@ def _experiment(mode: str, n_points: int):
         metrics=METRICS, chunk_size=CHUNK, dedup=False, **kw)
 
 
-def _child(mode: str, n_points: int, out_npz: str, stream_to: str) -> None:
-    """One benchmark arm: run, save the metric arrays for the parent's
-    bitwise comparison, report timing + peak RSS as a JSON line."""
-    import resource
-
-    from benchmarks import common as C
-
+def _run_arm(mode: str, n_points: int, tmp: str, trace_memory: bool):
+    """One run of one arm: ``(results, seconds, compiles, peak_mb)``;
+    ``peak_mb`` is the arm's peak traced host allocation, or None."""
     exp = _experiment(mode, n_points)
-    run_kw = {"stream_to": stream_to} if mode == "streamed" else {}
-    (res, compiles), us = C.timed(C.compile_counted, exp.run, **run_kw)
-    assert compiles == 1, (
-        f"{res.meta['n_chunks']} chunk launches must share one "
-        f"compilation, saw {compiles}")
+    run_kw = ({"stream_to": os.path.join(tmp, f"{n_points}.jsonl")}
+              if mode == "streamed" else {})
+    if trace_memory:
+        tracemalloc.start()
+    try:
+        (res, compiles), us = C.timed(C.compile_counted, exp.run, **run_kw)
+        peak_mb = (tracemalloc.get_traced_memory()[1] / 2**20
+                   if trace_memory else None)
+    finally:
+        if trace_memory:
+            tracemalloc.stop()
     assert res.meta["n_chunks"] >= 2, res.meta
     assert res.streamed == (mode == "streamed")
-    np.savez(out_npz, **{m: res.metric(m) for m in METRICS})
-    rss_mb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
-    print("RESULT " + json.dumps({
-        "mode": mode, "n_points": int(np.prod(res.shape)),
-        "sec": us / 1e6, "points_per_sec": np.prod(res.shape) / (us / 1e6),
-        "maxrss_mb": rss_mb, "n_chunks": res.meta["n_chunks"],
-        "compiles": compiles}), flush=True)
-
-
-def _run_arm(mode: str, n_points: int, tmp: str) -> tuple[dict, str]:
-    from benchmarks import common as C
-    out_npz = os.path.join(tmp, f"{mode}_{n_points}.npz")
-    stream_to = os.path.join(tmp, f"{mode}_{n_points}.jsonl")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(C.REPO_ROOT, "src"), C.REPO_ROOT]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--child", mode,
-         str(n_points), out_npz, stream_to],
-        env=env, cwd=C.REPO_ROOT, capture_output=True, text=True)
-    assert proc.returncode == 0, (
-        f"megasweep {mode}/{n_points} arm failed:\n{proc.stdout}\n"
-        f"{proc.stderr}")
-    line = [l for l in proc.stdout.splitlines()
-            if l.startswith("RESULT ")][-1]
-    return json.loads(line[len("RESULT "):]), out_npz
+    return res, us / 1e6, compiles, peak_mb
 
 
 def run() -> list[str]:
-    from benchmarks import common as C
-
     sizes = (2_000, 10_000) if C.QUICK else (10_000, 100_000)
     art: dict = {"quick": C.QUICK, "chunk": CHUNK, "n_req": N_REQ,
                  "metrics": list(METRICS)}
     rows = []
-    growth = {}
+    growth: dict = {}
+    compiles = {"full": 0, "streamed": 0}
     with tempfile.TemporaryDirectory() as tmp:
         for n in sizes:
-            full, full_npz = _run_arm("full", n, tmp)
-            streamed, str_npz = _run_arm("streamed", n, tmp)
+            arm = {}
+            for mode in ("full", "streamed"):
+                res, sec, c1, _ = _run_arm(mode, n, tmp, False)
+                _, _, c2, peak_mb = _run_arm(mode, n, tmp, True)
+                compiles[mode] += c1 + c2
+                pts = int(np.prod(res.shape))
+                arm[mode] = {"res": res, "sec": sec, "n_points": pts,
+                             "pps": pts / sec, "peak_mb": peak_mb}
+            full, streamed = arm["full"], arm["streamed"]
             assert full["n_points"] == streamed["n_points"]
-            a, b = np.load(full_npz), np.load(str_npz)
             for m in METRICS:
-                assert np.array_equal(a[m], b[m]), (
+                assert np.array_equal(full["res"].metric(m),
+                                      streamed["res"].metric(m)), (
                     f"streamed metrics diverge from materialized at "
                     f"n={n}, metric {m!r}")
-            speedup = streamed["points_per_sec"] / full["points_per_sec"]
-            for mode, r in (("full", full), ("streamed", streamed)):
-                art[f"pps_{mode}_{n}"] = round(r["points_per_sec"], 1)
-                art[f"rss_mb_{mode}_{n}"] = round(r["maxrss_mb"], 1)
-                growth.setdefault(mode, []).append(r["maxrss_mb"])
+            speedup = streamed["pps"] / full["pps"]
+            for mode, r in arm.items():
+                art[f"pps_{mode}_{n}"] = round(r["pps"], 1)
+                art[f"peak_mb_{mode}_{n}"] = round(r["peak_mb"], 1)
+                growth.setdefault(mode, []).append(r["peak_mb"])
             art[f"speedup_{n}"] = round(speedup, 3)
             # streamed never holds the object cells the full arm does
-            assert streamed["maxrss_mb"] <= full["maxrss_mb"] * 1.05, (
-                f"streamed peak RSS {streamed['maxrss_mb']:.0f} MB above "
-                f"materialized {full['maxrss_mb']:.0f} MB at n={n}")
+            assert streamed["peak_mb"] <= full["peak_mb"] * 1.05, (
+                f"streamed peak host allocation {streamed['peak_mb']:.0f} "
+                f"MB above materialized {full['peak_mb']:.0f} MB at n={n}")
             rows.append(C.csv_row(
                 f"megasweep_{n}", full["sec"] * 1e6,
-                f"pps_full={full['points_per_sec']:.0f}"
-                f";pps_streamed={streamed['points_per_sec']:.0f}"
+                f"pps_full={full['pps']:.0f}"
+                f";pps_streamed={streamed['pps']:.0f}"
                 f";speedup={speedup:.2f}"
-                f";rss_full_mb={full['maxrss_mb']:.0f}"
-                f";rss_streamed_mb={streamed['maxrss_mb']:.0f}"
-                f";chunks={streamed['n_chunks']};compiles=1"))
+                f";peak_full_mb={full['peak_mb']:.0f}"
+                f";peak_streamed_mb={streamed['peak_mb']:.0f}"
+                f";chunks={streamed['res'].meta['n_chunks']};compiles=1"))
+    for mode, c in compiles.items():
+        assert c == 1, (
+            f"every {mode} chunk launch must share one compilation, "
+            f"saw {c}")
     # peak host memory scales with the chunk, not the grid: the streamed
-    # arm's RSS growth across a {10,5}x grid stays far below the full
-    # arm's O(grid) object-cell growth
+    # arm's growth across a {10,5}x grid stays far below the full arm's
+    # O(grid) object-cell growth
     for mode in ("full", "streamed"):
-        art[f"rss_growth_mb_{mode}"] = round(
+        art[f"peak_growth_mb_{mode}"] = round(
             growth[mode][-1] - growth[mode][0], 1)
     if not C.QUICK:
         big = sizes[-1]
@@ -168,10 +159,5 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--child":
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        _child(sys.argv[2], int(sys.argv[3]), sys.argv[4], sys.argv[5])
-    else:
-        for r in run():
-            print(r)
+    for r in run():
+        print(r)

@@ -62,7 +62,7 @@ def _artifact_summaries() -> dict:
     at the repo root (the trajectory's per-run payload) — nested
     structures are skipped, so artifacts opt in to the trajectory by
     keeping their headline numbers flat (e.g. ``BENCH_megasweep.json``'s
-    points/sec, speedup and peak-RSS scalars)."""
+    points/sec, speedup and peak host memory scalars)."""
     out: dict = {}
     for name in sorted(os.listdir(C.REPO_ROOT)):
         if not (name.startswith("BENCH_") and name.endswith(".json")):

@@ -293,7 +293,7 @@ def _run_window(shape: SimShape, W: int, params: MechParams, trace: dict,
                             n_steps, collect_events)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 6, 7))
+@functools.partial(jax.jit, static_argnums=(0, 1, 5, 6, 9))
 def _run_window_batched(shape: SimShape, W: int, params: MechParams,
                         trace: dict, warmup_steps, n_steps: int,
                         collect_events: bool = True,

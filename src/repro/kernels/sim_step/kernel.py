@@ -18,9 +18,10 @@ one point per grid step:
   loaded once and reused by each grid step;
 * grid steps are independent by construction (points never communicate),
   so the sweep dimension is declared ``parallel`` to the TPU compiler
-  and interpret mode (the CPU fallback) simply runs them sequentially —
+  and interpret mode (on the CPU) simply runs them sequentially —
   with *identical* jnp semantics to the ref engine, which is what makes
-  the bitwise-parity contract testable on every backend.
+  the bitwise-parity contract testable.  Mosaic accepts this block
+  layout but not the scan body it wraps (see ``ops.MOSAIC_REFUSAL``).
 
 The launcher below is generic over pytrees so the trace-driven and the
 fused-synthesis entry points share one code path.
@@ -31,36 +32,27 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["grid_step_call"]
 
 
-def _stacked_spec(x):
-    nd = x.ndim - 1
-    return pl.BlockSpec((1,) + x.shape[1:], lambda i, _nd=nd: (i,) + (0,) * _nd)
+def _point_layout(shape: tuple) -> tuple:
+    """Per-point block shape with the grid axis kept out of the last two
+    dimensions (Mosaic tiles those; a ``(1,)``-blocked rank-1 leaf is
+    illegal there): one point's ``shape`` is laid out as ``(1, *shape)``
+    padded with unit dims to rank >= 3."""
+    return (1,) * max(1, 3 - len(shape)) + tuple(shape)
+
+
+def _stacked_spec(point_shape):
+    blk = _point_layout(point_shape)
+    return pl.BlockSpec(blk, lambda i, _nd=len(blk) - 1: (i,) + (0,) * _nd)
 
 
 def _shared_spec(x):
     nd = x.ndim
     return pl.BlockSpec(x.shape, lambda i, _nd=nd: (0,) * _nd)
-
-
-def _tpu_params():
-    """Best-effort ``parallel`` grid annotation; the pallas TPU params
-    class has moved across JAX versions, and the kernel is correct (just
-    less schedulable) without it."""
-    if jax.default_backend() != "tpu":
-        return {}
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None)
-        if cls is not None:
-            return {"compiler_params": cls(
-                dimension_semantics=("parallel",))}
-    except Exception:
-        pass
-    return {}
 
 
 def grid_step_call(stacked, shared, body_fn, *, interpret: bool):
@@ -104,7 +96,8 @@ def grid_step_call(stacked, shared, body_fn, *, interpret: bool):
 
     def kern(*refs):
         in_refs, out_refs = refs[:n_s + len(sh_live)], refs[n_s + len(sh_live):]
-        point = _rebuild(s_def, [r[...][0] for r in in_refs[:n_s]],
+        point = _rebuild(s_def, [r[...].reshape(x.shape[1:])
+                                 for r, x in zip(in_refs[:n_s], s_live)],
                          s_leaves, point=True)
         shr = _rebuild(sh_def, [r[...] for r in in_refs[n_s:]],
                        sh_leaves, point=False)
@@ -114,21 +107,21 @@ def grid_step_call(stacked, shared, body_fn, *, interpret: bool):
         for r, v in zip(out_refs, live):
             r[...] = jnp.asarray(v).reshape(r.shape)
 
+    grid_shape = lambda shape: (n_grid,) + _point_layout(shape)[1:]
     res = pl.pallas_call(
         kern,
         grid=(n_grid,),
-        in_specs=[_stacked_spec(x) for x in s_live]
+        in_specs=[_stacked_spec(x.shape[1:]) for x in s_live]
         + [_shared_spec(x) for x in sh_live],
-        out_specs=[pl.BlockSpec((1,) + s.shape,
-                                lambda i, _nd=len(s.shape): (i,) + (0,) * _nd)
-                   for s in o_live],
-        out_shape=[jax.ShapeDtypeStruct((n_grid,) + s.shape, s.dtype)
+        out_specs=[_stacked_spec(s.shape) for s in o_live],
+        out_shape=[jax.ShapeDtypeStruct(grid_shape(s.shape), s.dtype)
                    for s in o_live],
         interpret=interpret,
-        **({} if interpret else _tpu_params()),
-    )(*s_live, *sh_live)
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel",))}),
+    )(*[x.reshape(grid_shape(x.shape[1:])) for x in s_live], *sh_live)
     it = iter(list(res))
-    out_vals = [next(it) if 0 not in s.shape
+    out_vals = [next(it).reshape((n_grid,) + s.shape) if 0 not in s.shape
                 else jnp.zeros((n_grid,) + s.shape, s.dtype)
                 for s in o_leaves]
     return jax.tree_util.tree_unflatten(o_def, out_vals)

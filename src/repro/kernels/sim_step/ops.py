@@ -5,8 +5,9 @@ engines (``simulator._run_batched`` / ``_run_synth_batched``) and are
 what the ``sweep()`` / ``sweep_synth()`` entry points call when a grid
 selects ``backend="pallas"``.  On CPU the kernels run in Pallas
 interpret mode (same jnp semantics as the ref scan — the bitwise-parity
-fallback); on an accelerator they compile for real, grid-parallel over
-the sweep batch dimension.
+contract).  On any other platform they raise ``NotImplementedError``:
+Mosaic refuses the scan body (``MOSAIC_REFUSAL``), and nothing falls
+back to another engine behind the caller's back.
 
 The scan body itself is *shared* with the ref tier: the kernel body
 calls ``simulator._run_impl`` (and, on the synthetic path, the
@@ -28,8 +29,23 @@ from repro.kernels.sim_step.kernel import grid_step_call
 __all__ = ["run_sweep", "run_synth"]
 
 
-def _is_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+#: why the kernel cannot run compiled: Mosaic (the TPU Pallas compiler,
+#: JAX 0.9.0) has no lowering for the scan body's per-bank gathers and
+#: scatters — compiling ``_sweep_pallas`` for a v5e chip fails with
+#: "Unimplemented primitive in Pallas TPU lowering ...: dynamic_slice"
+#: (tests/test_tpu_compile.py)
+MOSAIC_REFUSAL = (
+    'backend="pallas" runs only in Pallas interpret mode on the CPU: '
+    "Mosaic refuses the sim_step scan body on {platform} (unimplemented "
+    "Pallas TPU lowering for dynamic_slice, the per-bank gathers); use "
+    'backend="ref"')
+
+
+def _require_cpu() -> None:
+    """The kernel runs in interpret mode on the CPU; refuse elsewhere."""
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise NotImplementedError(MOSAIC_REFUSAL.format(platform=platform))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4, 5, 6))
@@ -78,20 +94,17 @@ def _synth_pallas(shape, n_cores: int, max_len: int, stacked, wstack,
 
 
 def run_sweep(shape, stacked, trace, warmup, n_steps: int,
-              collect_events: bool = True, ns_geoms=None, ns_idx=None, *,
-              interpret: bool | None = None):
+              collect_events: bool = True, ns_geoms=None, ns_idx=None):
     """Kernel-tier analogue of ``simulator._run_batched``."""
-    interp = _is_cpu() if interpret is None else interpret
+    _require_cpu()
     return _sweep_pallas(shape, stacked, trace, warmup, n_steps,
-                         collect_events, interp, ns_geoms, ns_idx)
+                         collect_events, True, ns_geoms, ns_idx)
 
 
 def run_synth(shape, n_cores: int, max_len: int, stacked, wstack,
               ilstack, warmups, n_steps: int,
-              collect_events: bool = True, *,
-              interpret: bool | None = None):
+              collect_events: bool = True):
     """Kernel-tier analogue of ``simulator._run_synth_batched``."""
-    interp = _is_cpu() if interpret is None else interpret
+    _require_cpu()
     return _synth_pallas(shape, n_cores, max_len, stacked, wstack,
-                         ilstack, warmups, n_steps, collect_events,
-                         interp)
+                         ilstack, warmups, n_steps, collect_events, True)

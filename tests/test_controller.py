@@ -15,7 +15,7 @@
 import numpy as np
 import pytest
 
-from _parity import BITWISE_KEYS
+from _parity import BITWISE_KEYS, assert_cell_matches
 from repro.controller import engine as ctrl_engine
 from repro.core import simulator as sim_mod
 from repro.core.dram import DRAMConfig
@@ -23,6 +23,7 @@ from repro.core.simulator import (MechanismConfig, SimConfig, mech_params,
                                   sim_shape, simulate)
 from repro.core import mechanisms as registry
 from repro.core.traces import WorkloadSpec
+from repro.experiment import Experiment
 from repro.workloads.generator import materialize
 
 DRAM_2CH = DRAMConfig(n_channels=2, n_ranks=2, n_banks=8)
@@ -107,6 +108,24 @@ def test_cc_speedup_direction_preserved_both_tiers():
     assert sp["inorder"] >= 1.0
     assert sp["frfcfs"] >= 1.0
     assert abs(sp["frfcfs"] - sp["inorder"]) < 0.15, sp
+
+
+def test_trace_experiment_frfcfs_matches_simulate_one_compile():
+    """A trace-driven controller × mechanism grid through ``Experiment``
+    (the ``sweep()`` route: ``_run_window_batched`` with a static step
+    count and traced geometry tables) runs in ONE compile and matches
+    per-point ``simulate()`` bitwise, RLTL included."""
+    batch = materialize(WorkloadSpec(names=("mcf_like", "omnetpp_like"),
+                                     n_req=150, seed=4))
+    exp = Experiment(traces=batch, rltl=True,
+                     axes={"controller": ["inorder", "frfcfs"],
+                           "mechanism": ["base", "chargecache", "rltl"]})
+    before = ctrl_engine._run_window_batched._cache_size()
+    res = exp.run()
+    assert ctrl_engine._run_window_batched._cache_size() - before == 1
+    _, _, cfgs = exp.expand()
+    for cfg, got in zip(cfgs, res.cells.flat):
+        assert_cell_matches(simulate(batch, cfg), got, rltl=True)
 
 
 def test_rank_act_spacing_trrd_tfaw():
