@@ -129,9 +129,9 @@ def main() -> None:
         return
     from benchmarks import (aldram, capacity, charge_model_bench, duration,
                             energy, frfcfs, geometry, kernels_bench,
-                            megasweep, refresh, rltl, roofline_bench,
-                            serving_loop, serving_trace, simstep_bench,
-                            speedup, sweep_bench, workloads)
+                            megasweep, refresh, rltl, serving_loop,
+                            serving_trace, simstep_bench, speedup,
+                            sweep_bench, workloads)
     # (name, module, declared BENCH_* artifacts the module must emit)
     mods = [
         ("charge_model", charge_model_bench, ()),
@@ -150,7 +150,6 @@ def main() -> None:
         ("serving", serving_trace, ()),
         ("serving_loop", serving_loop, ("BENCH_serving.json",)),
         ("kernels", kernels_bench, ()),
-        ("roofline", roofline_bench, ()),
         ("megasweep", megasweep, ("BENCH_megasweep.json",)),
     ]
     print("name,us_per_call,derived")

@@ -3,6 +3,9 @@
 The faithful reproduction of the thesis's mechanism (see DESIGN.md §2.1).
 """
 
+# first, so the jit-cache listener hears every compile of the program
+from repro import obs  # noqa: F401
+
 from repro.core.timing import (TimingParams, TimingVec, DDR3_1600,
                                DDR3_1600_CC_1MS, lowered_for_duration,
                                ms_to_cycles, ns_to_cycles, CYCLE_NS)

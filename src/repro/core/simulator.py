@@ -71,6 +71,7 @@ from repro.core.traces import TraceBatch, WorkloadSpec, WORKLOAD_BY_NAME
 from repro.core import mechanisms as registry
 from repro.core import metrics as metrics_lib
 from repro.core.mechanisms import default_nuat_bins  # noqa: F401 (re-export)
+from repro.obs import span
 
 # np scalar so Pallas kernel bodies may close over it (see dram.NO_ROW)
 INF = np.int32(2**30)
@@ -1014,18 +1015,19 @@ def _rltl_np(events: Events | None, on_device: bool | None = None):
         return None, None
     if on_device is None:
         on_device = jax.default_backend() != "cpu"
-    if on_device:
-        hist, total = _rltl_hist_device(events)
-        return np.asarray(hist).astype(np.int64), \
-            np.asarray(total).astype(np.int64)
-    ev = Events(*(np.asarray(e) for e in events))
-    lead = ev.act_gid.shape[:-1]
-    hist = np.zeros(lead + (len(RLTL_EDGES_MS) + 1,), np.int64)
-    total = np.zeros(lead, np.int64)
-    for idx in np.ndindex(*lead):
-        hist[idx], total[idx] = _rltl_post_pass(
-            Events(*(x[idx] for x in ev)))
-    return hist, total
+    with span("rltl"):
+        if on_device:
+            hist, total = _rltl_hist_device(events)
+            return np.asarray(hist).astype(np.int64), \
+                np.asarray(total).astype(np.int64)
+        ev = Events(*(np.asarray(e) for e in events))
+        lead = ev.act_gid.shape[:-1]
+        hist = np.zeros(lead + (len(RLTL_EDGES_MS) + 1,), np.int64)
+        total = np.zeros(lead, np.int64)
+        for idx in np.ndindex(*lead):
+            hist[idx], total[idx] = _rltl_post_pass(
+                Events(*(x[idx] for x in ev)))
+        return hist, total
 
 
 def _device_trace(batch: TraceBatch) -> dict:
@@ -1304,17 +1306,20 @@ def _drain_batch(out, grid, lengths, n_grid: int,
     ``[grid, n_deps]`` int columns, or the full per-point stats dicts
     (``_finalize``)."""
     if reduce_keys is not None:
-        return np.asarray(out)[:n_grid]
+        with span("d2h"):
+            return np.asarray(out)[:n_grid]
     raw_stats, core_end, events = out
-    stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
-    core_np = np.asarray(core_end)
+    with span("d2h"):
+        stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
+        core_np = np.asarray(core_end)
     hist_np, total_np = _rltl_np(events)
-    return [
-        _finalize({k: v[g] for k, v in stats_np.items()}, core_np[g],
-                  (None, None) if hist_np is None
-                  else (hist_np[g], total_np[g]), lengths, grid[g])
-        for g in range(n_grid)
-    ]
+    with span("finalize"):
+        return [
+            _finalize({k: v[g] for k, v in stats_np.items()}, core_np[g],
+                      (None, None) if hist_np is None
+                      else (hist_np[g], total_np[g]), lengths, grid[g])
+            for g in range(n_grid)
+        ]
 
 
 def sweep(batch: TraceBatch, grid: Sequence[SimConfig],
@@ -1394,20 +1399,24 @@ def _launch_grid(shape, stacked, traces, warmups, n_steps: int,
 def _drain_grid(out, grid, batches, n_batch: int,
                 reduce_keys: tuple | None = None):
     if reduce_keys is not None:
-        return np.asarray(out)[:n_batch]
+        with span("d2h"):
+            return np.asarray(out)[:n_batch]
     raw_stats, core_end, events = out
-    stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}  # [B, G]
-    core_np = np.asarray(core_end)
+    with span("d2h"):
+        stats_np = {k: np.asarray(v)
+                    for k, v in raw_stats.items()}  # [B, G]
+        core_np = np.asarray(core_end)
     hist_np, total_np = _rltl_np(events)
     rows = []
     for b in range(n_batch):
-        row = []
-        for g in range(len(grid)):
-            rl = ((None, None) if hist_np is None
-                  else (hist_np[b, g], total_np[b, g]))
-            row.append(_finalize({k: v[b, g] for k, v in stats_np.items()},
-                                 core_np[b, g], rl, batches[b].length,
-                                 grid[g]))
+        with span("finalize"):
+            row = []
+            for g in range(len(grid)):
+                rl = ((None, None) if hist_np is None
+                      else (hist_np[b, g], total_np[b, g]))
+                row.append(_finalize(
+                    {k: v[b, g] for k, v in stats_np.items()},
+                    core_np[b, g], rl, batches[b].length, grid[g]))
         rows.append(row)
     return rows
 
@@ -1643,18 +1652,21 @@ def _launch_synth(shape, n_cores: int, max_len: int, stacked, wstack,
 def _drain_synth(out, grid, n_grid: int,
                  reduce_keys: tuple | None = None):
     if reduce_keys is not None:
-        return np.asarray(out)[:n_grid]
+        with span("d2h"):
+            return np.asarray(out)[:n_grid]
     raw_stats, core_end, events = out
-    stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
-    core_np = np.asarray(core_end)
+    with span("d2h"):
+        stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
+        core_np = np.asarray(core_end)
     hist_np, total_np = _rltl_np(events)
-    return [
-        _finalize({k: v[g] for k, v in stats_np.items()}, core_np[g],
-                  (None, None) if hist_np is None
-                  else (hist_np[g], total_np[g]),
-                  grid[g].workload.lengths(), grid[g])
-        for g in range(n_grid)
-    ]
+    with span("finalize"):
+        return [
+            _finalize({k: v[g] for k, v in stats_np.items()}, core_np[g],
+                      (None, None) if hist_np is None
+                      else (hist_np[g], total_np[g]),
+                      grid[g].workload.lengths(), grid[g])
+            for g in range(n_grid)
+        ]
 
 
 def sweep_synth(grid: Sequence[SimConfig], rltl: bool = True,

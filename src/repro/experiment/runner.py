@@ -43,6 +43,12 @@ a trace-mode launch drains ``len(batches) × n_valid`` points at once
 launch drains ``n_valid``.  Drains happen in launch order, so ``done``
 is monotone regardless of pipeline depth (tests/test_streaming.py).
 
+**Spans** (``repro.obs``): ``repro/run`` holds the phases above as
+``repro/expand`` (1–3), ``repro/stage`` (4), one ``repro/launch`` and
+one ``repro/drain`` per launch (5, with ``chunk=``; a drain holds the
+engine's ``repro/d2h``, ``repro/rltl`` and ``repro/finalize`` and the
+runner's ``repro/fan_out``) and ``repro/assemble`` (6).
+
 Every cell is bitwise-identical to a direct ``sweep()`` /
 ``sweep_traces()`` of the same expanded grid (tests/test_experiment.py),
 chunked, pipelined, reduced or not.
@@ -66,6 +72,7 @@ from repro.core.traces import pad_batch_to
 from repro.experiment import registry
 from repro.experiment.results import Results, ResultsWriter
 from repro.experiment.spec import Experiment
+from repro.obs import span
 
 #: default per-device memory budget for auto-chunking (MiB)
 DEFAULT_BUDGET_MB = 1024.0
@@ -236,6 +243,8 @@ class ChunkScheduler:
     callbacks and stream writes) happen strictly in launch order while
     later chunks' dispatch overlaps earlier chunks' device compute.
     ``depth=0`` degenerates to launch-then-drain serial blocking.
+    Each launch and each drain is a span (``repro/launch``,
+    ``repro/drain``) carrying the launch's index as ``chunk``.
 
     The device list is an abstraction seam: ``jax.devices()`` today; a
     mesh's device axis tomorrow (the cross-host mega-sweep, ROADMAP).
@@ -249,136 +258,147 @@ class ChunkScheduler:
 
     def run(self, work: Iterable[tuple[Callable, Callable]]) -> None:
         pending: deque = deque()
-        for launch, finish in work:
-            pending.append((launch(), finish))
+        for chunk, (launch, finish) in enumerate(work):
+            with span("launch", chunk=chunk):
+                pending.append((chunk, launch(), finish))
             while len(pending) > self.max_inflight:
-                out, fin = pending.popleft()
-                fin(out)
+                self._drain(*pending.popleft())
         while pending:
-            out, fin = pending.popleft()
-            fin(out)
+            self._drain(*pending.popleft())
+
+    @staticmethod
+    def _drain(chunk: int, out, finish: Callable) -> None:
+        with span("drain", chunk=chunk):
+            finish(out)
 
 
 def run_experiment(exp: Experiment, progress=None,
                    stream_to: str | None = None) -> Results:
-    labeled, trace_items = exp.trace_items()
-    cfg_dims, cfg_coords, configs = exp.expand()
-    if not configs:
-        configs = [exp.base]
-    serving = exp.traces is None and configs[0].serving is not None
-    synth = exp.traces is None and not serving
-    mode = "serving" if serving else ("synth" if synth else "trace")
-    unique, index_map = _dedup(configs, exp.dedup, mode)
+    with span("run"):
+        return _run(exp, progress, stream_to)
 
-    if serving:
-        for cfg in unique:
-            assert cfg.serving is not None, (
-                "a serving experiment (base.serving set) must set "
-                "cfg.serving on every grid point")
-        # one pseudo trace row so chunk fan-out/assembly is shared below
-        trace_items = [(None, None)]
-    if synth:
-        for cfg in unique:
-            assert cfg.workload is not None and cfg.workload.names, (
-                "Experiment(traces=None) is the synthetic mode: every "
-                "grid point needs a WorkloadSpec (add a 'workload' axis "
-                "or set base.workload)")
-        # fail up front (not mid-launch) on mixed core counts: the
-        # streamed engine shares one [C, L] stream shape per grid —
-        # unlike the trace-driven path, which groups batches by C
-        cores = {cfg.workload.n_cores for cfg in unique}
-        assert len(cores) == 1, (
-            f"a synthetic grid must share one core count, got {sorted(cores)}: "
-            f"split the experiment per core count (the workload axis mixes "
-            f"single-core names with multi-core mixes)")
-        # one pseudo trace row so chunk fan-out/assembly is shared below
-        trace_items = [(None, None)]
 
-    # ---- the §13 reduce contract ------------------------------------
-    reduced = exp.reduce is not None
-    if reduced:
-        assert not exp.rltl, (
-            "reduce= lowers scalar ingredients only; RLTL histograms "
-            "need the full-stats path (reduce=None)")
-        assert not exp.trace_metrics, (
-            "reduce= streams device-computed metrics only; trace_metrics "
-            "extras need the full-stats path")
+def _run(exp: Experiment, progress, stream_to: str | None) -> Results:
+    with span("expand"):
+        labeled, trace_items = exp.trace_items()
+        cfg_dims, cfg_coords, configs = exp.expand()
+        if not configs:
+            configs = [exp.base]
+        serving = exp.traces is None and configs[0].serving is not None
+        synth = exp.traces is None and not serving
+        mode = "serving" if serving else ("synth" if synth else "trace")
+        unique, index_map = _dedup(configs, exp.dedup, mode)
+
         if serving:
-            from repro.serving.loop.engine import SERVE_REDUCE_KEYS
-            available = SERVE_REDUCE_KEYS
+            for cfg in unique:
+                assert cfg.serving is not None, (
+                    "a serving experiment (base.serving set) must set "
+                    "cfg.serving on every grid point")
+            # one pseudo trace row so chunk fan-out/assembly is shared below
+            trace_items = [(None, None)]
+        if synth:
+            for cfg in unique:
+                assert cfg.workload is not None and cfg.workload.names, (
+                    "Experiment(traces=None) is the synthetic mode: every "
+                    "grid point needs a WorkloadSpec (add a 'workload' axis "
+                    "or set base.workload)")
+            # fail up front (not mid-launch) on mixed core counts: the
+            # streamed engine shares one [C, L] stream shape per grid —
+            # unlike the trace-driven path, which groups batches by C
+            cores = {cfg.workload.n_cores for cfg in unique}
+            assert len(cores) == 1, (
+                f"a synthetic grid must share one core count, got "
+                f"{sorted(cores)}: split the experiment per core count (the "
+                f"workload axis mixes single-core names with multi-core "
+                f"mixes)")
+            # one pseudo trace row so chunk fan-out/assembly is shared below
+            trace_items = [(None, None)]
+
+        # ---- the §13 reduce contract ------------------------------------
+        reduced = exp.reduce is not None
+        if reduced:
+            assert not exp.rltl, (
+                "reduce= lowers scalar ingredients only; RLTL histograms "
+                "need the full-stats path (reduce=None)")
+            assert not exp.trace_metrics, (
+                "reduce= streams device-computed metrics only; trace_metrics "
+                "extras need the full-stats path")
+            if serving:
+                from repro.serving.loop.engine import SERVE_REDUCE_KEYS
+                available = SERVE_REDUCE_KEYS
+            else:
+                available = sim_mod.REDUCE_KEYS
+            resolved = metrics_lib.resolve(exp.reduce_metrics(), available)
+            reduce_keys = metrics_lib.deps_for(resolved)
+            out_metrics = tuple(m.name for m in resolved)
         else:
-            available = sim_mod.REDUCE_KEYS
-        resolved = metrics_lib.resolve(exp.reduce_metrics(), available)
-        reduce_keys = metrics_lib.deps_for(resolved)
-        out_metrics = tuple(m.name for m in resolved)
-    else:
-        reduce_keys = None
-        out_metrics = tuple(exp.metrics)
+            reduce_keys = None
+            out_metrics = tuple(exp.metrics)
 
-    # group traces by core count; pad within a group to the longest trace
-    groups: dict[int, list] = {}
-    if exp.traces is not None:
-        for pos, (label, batch) in enumerate(trace_items):
-            groups.setdefault(batch.gap.shape[0], []).append((pos, batch))
+        # group traces by core count; pad within a group to the longest trace
+        groups: dict[int, list] = {}
+        if exp.traces is not None:
+            for pos, (label, batch) in enumerate(trace_items):
+                groups.setdefault(batch.gap.shape[0], []).append((pos, batch))
 
-    depth = max(0, int(exp.pipeline_depth))
-    chunk = exp.chunk_size or _auto_chunk(unique, groups, exp.rltl,
-                                          exp.memory_budget_mb, mode,
-                                          pipeline_depth=depth)
-    chunk = max(1, min(chunk, len(unique)))
-    n_unique = len(unique)
-    n_chunks = -(-n_unique // chunk)
-    # per-chunk row indices into the staged unique grid; the tail chunk
-    # pads by repeating its last point so every launch shares one
-    # stacked-params shape (same avals -> the one compilation)
-    chunk_idx = [np.minimum(np.arange(ci * chunk, (ci + 1) * chunk),
-                            n_unique - 1) for ci in range(n_chunks)]
-    chunk_cfgs = [[unique[i] for i in idx] for idx in chunk_idx]
-    n_valid = [min(chunk, n_unique - ci * chunk) for ci in range(n_chunks)]
+        depth = max(0, int(exp.pipeline_depth))
+        chunk = exp.chunk_size or _auto_chunk(unique, groups, exp.rltl,
+                                              exp.memory_budget_mb, mode,
+                                              pipeline_depth=depth)
+        chunk = max(1, min(chunk, len(unique)))
+        n_unique = len(unique)
+        n_chunks = -(-n_unique // chunk)
+        # per-chunk row indices into the staged unique grid; the tail chunk
+        # pads by repeating its last point so every launch shares one
+        # stacked-params shape (same avals -> the one compilation)
+        chunk_idx = [np.minimum(np.arange(ci * chunk, (ci + 1) * chunk),
+                                n_unique - 1) for ci in range(n_chunks)]
+        chunk_cfgs = [[unique[i] for i in idx] for idx in chunk_idx]
+        n_valid = [min(chunk, n_unique - ci * chunk) for ci in range(n_chunks)]
 
-    def rows_of(tree, idx):
-        """Per-chunk view of once-staged [n_unique, ...] numpy leaves."""
-        return jax.tree_util.tree_map(lambda a: np.asarray(a)[idx], tree)
+        def rows_of(tree, idx):
+            """Per-chunk view of once-staged [n_unique, ...] numpy leaves."""
+            return jax.tree_util.tree_map(lambda a: np.asarray(a)[idx], tree)
 
-    # ---- dense labeled frame + streaming sinks ----------------------
-    dims = ((exp.trace_dim,) + cfg_dims) if labeled else cfg_dims
-    coords = dict(cfg_coords)
-    if labeled:
-        coords[exp.trace_dim] = tuple(label for label, _ in trace_items)
-    shape = tuple(len(coords[d]) for d in dims)
-    cfg_shape = tuple(len(cfg_coords[d]) for d in cfg_dims)
-    n_flat = int(np.prod(cfg_shape, dtype=np.int64)) if cfg_shape else 1
-    imap = np.asarray(index_map, np.int64)
-    n_rows = len(trace_items)
+        # ---- dense labeled frame + streaming sinks ----------------------
+        dims = ((exp.trace_dim,) + cfg_dims) if labeled else cfg_dims
+        coords = dict(cfg_coords)
+        if labeled:
+            coords[exp.trace_dim] = tuple(label for label, _ in trace_items)
+        shape = tuple(len(coords[d]) for d in dims)
+        cfg_shape = tuple(len(cfg_coords[d]) for d in cfg_dims)
+        n_flat = int(np.prod(cfg_shape, dtype=np.int64)) if cfg_shape else 1
+        imap = np.asarray(index_map, np.int64)
+        n_rows = len(trace_items)
 
-    meta = {"n_points": len(configs) * n_rows,
-            "n_configs": len(configs), "n_unique": n_unique,
-            "chunk_size": chunk, "n_chunks": n_chunks,
-            # synth mode has no trace groups: one launch per chunk
-            "n_launches": n_chunks * max(1, len(groups)),
-            "mode": mode, "pipeline_depth": depth}
-    if reduced:
-        meta["reduce_keys"] = tuple(reduce_keys)
+        meta = {"n_points": len(configs) * n_rows,
+                "n_configs": len(configs), "n_unique": n_unique,
+                "chunk_size": chunk, "n_chunks": n_chunks,
+                # synth mode has no trace groups: one launch per chunk
+                "n_launches": n_chunks * max(1, len(groups)),
+                "mode": mode, "pipeline_depth": depth}
+        if reduced:
+            meta["reduce_keys"] = tuple(reduce_keys)
 
-    writer = (ResultsWriter(stream_to, dims, coords, out_metrics,
-                            meta=meta) if stream_to else None)
+        writer = (ResultsWriter(stream_to, dims, coords, out_metrics,
+                                meta=meta) if stream_to else None)
 
-    by_trace: list[list] = [[None] * n_unique for _ in trace_items]
-    flat_data = ({m: np.full((n_rows, n_flat), np.nan)
-                  for m in out_metrics} if reduced else None)
-    aggs: dict[str, tuple] = {}
-    if exp.aggregate:
-        assert reduced, "aggregate= needs reduce= (streamed metrics)"
-        by_name = {m.name: m for m in resolved}
-        for rn, (agg_name, metric_name) in dict(exp.aggregate).items():
-            assert metric_name in by_name, (
-                f"aggregate {rn!r} refers to {metric_name!r}, which is "
-                f"not among the reduced metrics {out_metrics}")
-            aggs[rn] = (metrics_lib.make_aggregator(
-                agg_name, by_name[metric_name]), metric_name)
+        by_trace: list[list] = [[None] * n_unique for _ in trace_items]
+        flat_data = ({m: np.full((n_rows, n_flat), np.nan)
+                      for m in out_metrics} if reduced else None)
+        aggs: dict[str, tuple] = {}
+        if exp.aggregate:
+            assert reduced, "aggregate= needs reduce= (streamed metrics)"
+            by_name = {m.name: m for m in resolved}
+            for rn, (agg_name, metric_name) in dict(exp.aggregate).items():
+                assert metric_name in by_name, (
+                    f"aggregate {rn!r} refers to {metric_name!r}, which is "
+                    f"not among the reduced metrics {out_metrics}")
+                aggs[rn] = (metrics_lib.make_aggregator(
+                    agg_name, by_name[metric_name]), metric_name)
 
-    total = n_rows * n_unique
-    state = {"done": 0}
+        total = n_rows * n_unique
+        state = {"done": 0}
 
     def advance(n):
         state["done"] += n
@@ -429,170 +449,169 @@ def run_experiment(exp: Experiment, progress=None,
                                else float(v))
         writer.write(t * n_flat + pos, rows)
 
-    # ---- stage once, then build the launch/drain work list ----------
-    # controller tier of the whole unique grid: one shared static window
-    # size so every chunk rides one window-engine compile (DESIGN.md §15)
-    ctrl, win = sim_mod._launch_controller(unique)
-    work: list[tuple[Callable, Callable]] = []
-
-    if serving:
-        from repro.serving.loop import engine as serve_eng
-        sshape, sparams, swarmups = serve_eng.stage_serving(
-            unique, unique, collect_steps=False)
-        for ci in range(n_chunks):
-            pch = rows_of(sparams, chunk_idx[ci])
-            wch = swarmups[chunk_idx[ci]]
-
-            def launch(pch=pch, wch=wch):
-                return serve_eng._launch_serving(
-                    sshape, pch, wch, None, chunk, reduce_keys)
-
-            def finish(out, ci=ci):
-                row = serve_eng._drain_serving(
-                    out, chunk_cfgs[ci], sshape, chunk, reduce_keys)
-                if reduced:
-                    fan_reduced(0, ci, row)
-                else:
-                    fan_full(0, ci, list(row))
-                advance(n_valid[ci])
-
-            work.append((launch, finish))
-
-    if synth:
-        (yshape, n_cores, max_len, n_steps, ystacked, wstack, ilstack,
-         ywarmups) = sim_mod._stage_synth(unique, unique)
-        backend = sim_mod._uniform_backend(unique)
-        for ci in range(n_chunks):
-            sch = rows_of(ystacked, chunk_idx[ci])
-            wch = rows_of(wstack, chunk_idx[ci])
-            ich = rows_of(ilstack, chunk_idx[ci])
-            uch = ywarmups[chunk_idx[ci]]
-
-            def launch(sch=sch, wch=wch, ich=ich, uch=uch):
-                return sim_mod._launch_synth(
-                    yshape, n_cores, max_len, sch, wch, ich, uch,
-                    n_steps, exp.rltl, chunk, backend=backend,
-                    reduce_keys=reduce_keys, controller=ctrl,
-                    window=win)
-
-            def finish(out, ci=ci):
-                row = sim_mod._drain_synth(out, chunk_cfgs[ci], chunk,
-                                           reduce_keys)
-                if reduced:
-                    fan_reduced(0, ci, row)
-                else:
-                    fan_full(0, ci, list(row))
-                advance(n_valid[ci])
-
-            work.append((launch, finish))
-
-    if mode == "trace":
-        tshape, tstacked = sim_mod._grid_shape_and_params(unique, unique)
-        ns_geoms, ns_idx = sim_mod._hoist_geoms(unique, unique)
-        ns_idx = np.asarray(ns_idx)
-        backend = sim_mod._uniform_backend(unique)
-        single = not labeled and len(trace_items) == 1
-        for batches in groups.values():
-            max_len = max(b.gap.shape[1] for _, b in batches)
-            padded = [pad_batch_to(b, max_len) for _, b in batches]
-            if single:
-                trace = sim_mod._device_trace(padded[0])
-                n_req = int(padded[0].length.sum())
-                assert n_req < 2**24, (
-                    "trace too long for the int32 cycle horizon")
+    def fan_out(t: int, ci: int, row):
+        """Hand one drained chunk row to the mode's fan-out."""
+        with span("fan_out"):
+            if reduced:
+                fan_reduced(t, ci, row)
             else:
-                assert backend == "ref", (
-                    "sweep_traces runs the ref engine only; use a single "
-                    "unlabeled batch for the pallas tier")
-                traces = jax.tree_util.tree_map(
-                    lambda *xs: np.stack(xs),
-                    *[sim_mod._device_trace(b) for b in padded])
-                n_cores_g, max_len_g = padded[0].gap.shape
-                n_steps_g = n_cores_g * max_len_g
-                assert n_steps_g < 2**24, (
-                    "trace too long for the int32 cycle horizon")
+                fan_full(t, ci, list(row))
+
+    # ---- stage once, then build the launch/drain work list ----------
+    with span("stage"):
+        # controller tier of the whole unique grid: one shared static
+        # window size so every chunk rides one window-engine compile
+        # (DESIGN.md §15)
+        ctrl, win = sim_mod._launch_controller(unique)
+        work: list[tuple[Callable, Callable]] = []
+
+        if serving:
+            from repro.serving.loop import engine as serve_eng
+            sshape, sparams, swarmups = serve_eng.stage_serving(
+                unique, unique, collect_steps=False)
             for ci in range(n_chunks):
-                sch = rows_of(tstacked, chunk_idx[ci])
-                nch = ns_idx[chunk_idx[ci]]
-                cfg0 = chunk_cfgs[ci][0]
-                if single:
-                    warmup = np.int32(int(cfg0.warmup_frac * n_req))
+                pch = rows_of(sparams, chunk_idx[ci])
+                wch = swarmups[chunk_idx[ci]]
 
-                    def launch(sch=sch, nch=nch, warmup=warmup):
-                        return sim_mod._launch_batch(
-                            tshape, sch, trace, warmup, n_req, exp.rltl,
-                            ns_geoms, nch, chunk, backend=backend,
-                            reduce_keys=reduce_keys, controller=ctrl,
-                            window=win)
+                def launch(pch=pch, wch=wch):
+                    return serve_eng._launch_serving(
+                        sshape, pch, wch, None, chunk, reduce_keys)
 
-                    def finish(out, ci=ci, batches=batches):
-                        row = sim_mod._drain_batch(
-                            out, chunk_cfgs[ci], padded[0].length, chunk,
-                            reduce_keys)
-                        t = batches[0][0]
-                        if reduced:
-                            fan_reduced(t, ci, row)
-                        else:
-                            fan_full(t, ci, list(row))
-                        advance(n_valid[ci])
-                else:
-                    warmups = np.asarray(
-                        [int(cfg0.warmup_frac * int(b.length.sum()))
-                         for b in padded], np.int32)
-
-                    def launch(sch=sch, nch=nch, warmups=warmups,
-                               traces=traces, n_steps_g=n_steps_g):
-                        return sim_mod._launch_grid(
-                            tshape, sch, traces, warmups, n_steps_g,
-                            exp.rltl, ns_geoms, nch, len(padded),
-                            reduce_keys, controller=ctrl, window=win)
-
-                    def finish(out, ci=ci, batches=batches,
-                               padded=padded):
-                        rows = sim_mod._drain_grid(
-                            out, chunk_cfgs[ci], padded, len(padded),
-                            reduce_keys)
-                        for (pos, _), row in zip(batches, rows):
-                            if reduced:
-                                fan_reduced(pos, ci, row)
-                            else:
-                                fan_full(pos, ci, list(row))
-                        advance(len(batches) * n_valid[ci])
+                def finish(out, ci=ci):
+                    row = serve_eng._drain_serving(
+                        out, chunk_cfgs[ci], sshape, chunk, reduce_keys)
+                    fan_out(0, ci, row)
+                    advance(n_valid[ci])
 
                 work.append((launch, finish))
+
+        if synth:
+            (yshape, n_cores, max_len, n_steps, ystacked, wstack, ilstack,
+             ywarmups) = sim_mod._stage_synth(unique, unique)
+            backend = sim_mod._uniform_backend(unique)
+            for ci in range(n_chunks):
+                sch = rows_of(ystacked, chunk_idx[ci])
+                wch = rows_of(wstack, chunk_idx[ci])
+                ich = rows_of(ilstack, chunk_idx[ci])
+                uch = ywarmups[chunk_idx[ci]]
+
+                def launch(sch=sch, wch=wch, ich=ich, uch=uch):
+                    return sim_mod._launch_synth(
+                        yshape, n_cores, max_len, sch, wch, ich, uch,
+                        n_steps, exp.rltl, chunk, backend=backend,
+                        reduce_keys=reduce_keys, controller=ctrl,
+                        window=win)
+
+                def finish(out, ci=ci):
+                    row = sim_mod._drain_synth(out, chunk_cfgs[ci], chunk,
+                                               reduce_keys)
+                    fan_out(0, ci, row)
+                    advance(n_valid[ci])
+
+                work.append((launch, finish))
+
+        if mode == "trace":
+            tshape, tstacked = sim_mod._grid_shape_and_params(unique, unique)
+            ns_geoms, ns_idx = sim_mod._hoist_geoms(unique, unique)
+            ns_idx = np.asarray(ns_idx)
+            backend = sim_mod._uniform_backend(unique)
+            single = not labeled and len(trace_items) == 1
+            for batches in groups.values():
+                max_len = max(b.gap.shape[1] for _, b in batches)
+                padded = [pad_batch_to(b, max_len) for _, b in batches]
+                if single:
+                    trace = sim_mod._device_trace(padded[0])
+                    n_req = int(padded[0].length.sum())
+                    assert n_req < 2**24, (
+                        "trace too long for the int32 cycle horizon")
+                else:
+                    assert backend == "ref", (
+                        "sweep_traces runs the ref engine only; use a single "
+                        "unlabeled batch for the pallas tier")
+                    traces = jax.tree_util.tree_map(
+                        lambda *xs: np.stack(xs),
+                        *[sim_mod._device_trace(b) for b in padded])
+                    n_cores_g, max_len_g = padded[0].gap.shape
+                    n_steps_g = n_cores_g * max_len_g
+                    assert n_steps_g < 2**24, (
+                        "trace too long for the int32 cycle horizon")
+                for ci in range(n_chunks):
+                    sch = rows_of(tstacked, chunk_idx[ci])
+                    nch = ns_idx[chunk_idx[ci]]
+                    cfg0 = chunk_cfgs[ci][0]
+                    if single:
+                        warmup = np.int32(int(cfg0.warmup_frac * n_req))
+
+                        def launch(sch=sch, nch=nch, warmup=warmup):
+                            return sim_mod._launch_batch(
+                                tshape, sch, trace, warmup, n_req, exp.rltl,
+                                ns_geoms, nch, chunk, backend=backend,
+                                reduce_keys=reduce_keys, controller=ctrl,
+                                window=win)
+
+                        def finish(out, ci=ci, batches=batches):
+                            row = sim_mod._drain_batch(
+                                out, chunk_cfgs[ci], padded[0].length, chunk,
+                                reduce_keys)
+                            t = batches[0][0]
+                            fan_out(t, ci, row)
+                            advance(n_valid[ci])
+                    else:
+                        warmups = np.asarray(
+                            [int(cfg0.warmup_frac * int(b.length.sum()))
+                             for b in padded], np.int32)
+
+                        def launch(sch=sch, nch=nch, warmups=warmups,
+                                   traces=traces, n_steps_g=n_steps_g):
+                            return sim_mod._launch_grid(
+                                tshape, sch, traces, warmups, n_steps_g,
+                                exp.rltl, ns_geoms, nch, len(padded),
+                                reduce_keys, controller=ctrl, window=win)
+
+                        def finish(out, ci=ci, batches=batches,
+                                   padded=padded):
+                            rows = sim_mod._drain_grid(
+                                out, chunk_cfgs[ci], padded, len(padded),
+                                reduce_keys)
+                            for (pos, _), row in zip(batches, rows):
+                                fan_out(pos, ci, row)
+                            advance(len(batches) * n_valid[ci])
+
+                    work.append((launch, finish))
 
     ChunkScheduler(depth=depth).run(work)
     assert state["done"] == total, (state["done"], total)
 
     # ---- assemble ----------------------------------------------------
-    if reduced:
-        agg_out = {}
-        for rn, (agg, _) in aggs.items():
-            r = agg.result()
-            if isinstance(r, dict) and "flat_index" in r \
-                    and r["flat_index"] is not None:
-                idx = (np.unravel_index(r["flat_index"], shape)
-                       if shape else ())
-                r = {**r, "coords": {d: coords[d][int(i)]
-                                     for d, i in zip(dims, idx)}}
-            agg_out[rn] = r
-        if aggs:
-            meta["aggregates"] = agg_out
-        if writer is not None:
-            writer.close(meta={"aggregates": agg_out} if aggs else {})
-        data = {m: np.ascontiguousarray(a.reshape(shape))
-                for m, a in flat_data.items()}
-        return Results(dims=dims, coords=coords, data=data,
-                       metrics=out_metrics, meta=meta)
+    with span("assemble"):
+        if reduced:
+            agg_out = {}
+            for rn, (agg, _) in aggs.items():
+                r = agg.result()
+                if isinstance(r, dict) and "flat_index" in r \
+                        and r["flat_index"] is not None:
+                    idx = (np.unravel_index(r["flat_index"], shape)
+                           if shape else ())
+                    r = {**r, "coords": {d: coords[d][int(i)]
+                                         for d, i in zip(dims, idx)}}
+                agg_out[rn] = r
+            if aggs:
+                meta["aggregates"] = agg_out
+            if writer is not None:
+                writer.close(meta={"aggregates": agg_out} if aggs else {})
+            data = {m: np.ascontiguousarray(a.reshape(shape))
+                    for m, a in flat_data.items()}
+            return Results(dims=dims, coords=coords, data=data,
+                           metrics=out_metrics, meta=meta)
 
-    if writer is not None:
-        writer.close()
-    cells = np.empty(shape, object)
-    for t, (label, _) in enumerate(trace_items):
-        extra = dict((exp.trace_metrics or {}).get(label, {}))
-        for flat, u in enumerate(index_map):
-            idx = np.unravel_index(flat, cfg_shape) if cfg_shape else ()
-            full = ((t,) + tuple(idx)) if labeled else tuple(idx)
-            cells[full] = {**by_trace[t][u], **extra}
-    return Results(dims=dims, coords=coords, cells=cells,
-                   metrics=out_metrics, meta=meta)
+        if writer is not None:
+            writer.close()
+        cells = np.empty(shape, object)
+        for t, (label, _) in enumerate(trace_items):
+            extra = dict((exp.trace_metrics or {}).get(label, {}))
+            for flat, u in enumerate(index_map):
+                idx = np.unravel_index(flat, cfg_shape) if cfg_shape else ()
+                full = ((t,) + tuple(idx)) if labeled else tuple(idx)
+                cells[full] = {**by_trace[t][u], **extra}
+        return Results(dims=dims, coords=coords, cells=cells,
+                       metrics=out_metrics, meta=meta)

@@ -1,1 +1,1 @@
-"""Launcher: production meshes, dry-run, train/serve drivers."""
+"""Launcher: production meshes, train/serve drivers."""

@@ -1,0 +1,69 @@
+"""The program's own observability: host spans and jit-cache counters.
+
+``span(name, **args)`` marks a host phase of a study (``repro/<name>``)
+in the JAX profiler's trace, on the same clock as the device's XLA
+module events, so a profile of ``Experiment.run()`` says what the host
+was doing while the device was idle.  With no trace running a span
+costs about a microsecond.  Spans sit at host boundaries only: never
+inside a jitted function, never per grid point or per scan step.
+
+``jit_cache()`` returns what JAX's compile path has cost this process
+so far, per phase and per function: tracing to a jaxpr, lowering to an
+MLIR module, and the backend compile, which also wraps a load from the
+persistent compilation cache (timed apart as ``cache_load``).  The
+listener that feeds it is registered once, when this module is first
+imported (``repro.core`` imports it before anything can compile).
+
+This module is the only one in the program that touches
+``jax.profiler`` or ``jax.monitoring``.
+"""
+
+from __future__ import annotations
+
+import threading
+
+import jax
+
+#: ``jax.monitoring`` duration events -> the phase ``jit_cache`` reports
+PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+_lock = threading.Lock()
+_counts: dict[tuple[str, str], list] = {}
+
+
+def span(name: str, **args):
+    """A profiler span ``repro/<name>`` with ``args`` as its stats."""
+    return jax.profiler.TraceAnnotation("repro/" + name, **args)
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    phase = PHASES.get(event)
+    if phase is None:
+        return
+    key = (phase, str(kwargs.get("fun_name", "")))
+    with _lock:
+        entry = _counts.setdefault(key, [0, 0.0])
+        entry[0] += 1
+        entry[1] += duration
+
+
+def jit_cache() -> dict[str, dict[str, tuple[int, float]]]:
+    """``{phase: {function: (events, seconds)}}`` since the process
+    started, for the phases ``trace``, ``lower``, ``compile`` and
+    ``cache_load`` (a phase with no event yet is absent).  ``compile``
+    counts one event per executable built or loaded; ``cache_load`` is
+    the part of ``compile`` spent reading the persistent cache, and
+    JAX does not name its function (key ``""``)."""
+    out: dict[str, dict[str, tuple[int, float]]] = {}
+    with _lock:
+        for (phase, fun), (n, s) in _counts.items():
+            out.setdefault(phase, {})[fun] = (n, s)
+    return out
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)

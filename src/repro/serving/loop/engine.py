@@ -45,6 +45,7 @@ import numpy as np
 from repro.core import hcrac as hcl
 from repro.core import metrics as metrics_lib
 from repro.core import simulator as sim_mod
+from repro.obs import span
 from repro.serving.loop import policies as pol_mod
 from repro.serving.loop.spec import ServingSpec
 from repro.workloads import arrivals as arr_mod
@@ -503,29 +504,33 @@ def _launch_serving(shape: ServingShape, params: ServingParams, warmups,
 def _drain_serving(out, grid, shape: ServingShape, n_grid: int,
                    reduce_keys: tuple | None = None):
     if reduce_keys is not None:
-        return np.asarray(out)[:n_grid]
+        with span("d2h"):
+            return np.asarray(out)[:n_grid]
     sim_stats, serve_stats, final_now, ys = out
-    sim_np = {k: np.asarray(v) for k, v in sim_stats.items()}
-    serve_np = {k: np.asarray(v) for k, v in serve_stats.items()}
-    now_np = np.asarray(final_now)
-    ys_np = (None if ys is None
-             else tuple(np.asarray(y) for y in ys))
+    with span("d2h"):
+        sim_np = {k: np.asarray(v) for k, v in sim_stats.items()}
+        serve_np = {k: np.asarray(v) for k, v in serve_stats.items()}
+        now_np = np.asarray(final_now)
+        ys_np = (None if ys is None
+                 else tuple(np.asarray(y) for y in ys))
     n_steps = shape.n_steps
     out_rows = []
-    for g in range(n_grid):
-        res = sim_mod._finalize(
-            {k: v[g] for k, v in sim_np.items()}, now_np[g:g + 1],
-            (None, None), np.asarray([grid[g].serving.n_reqs]), grid[g])
-        for k in SERVE_STAT_KEYS:
-            res[k] = int(serve_np[k][g])
-        res["n_steps"] = n_steps
-        # derived serving scalars come from the same registry table the
-        # reduce path applies — one formula source (DESIGN.md §13)
-        metrics_lib.finalize_scalars(res)
-        if ys_np is not None:
-            res["steps"] = {"occ": ys_np[0][g], "qlen": ys_np[1][g],
-                            "arrivals": ys_np[2][g]}
-        out_rows.append(res)
+    with span("finalize"):
+        for g in range(n_grid):
+            res = sim_mod._finalize(
+                {k: v[g] for k, v in sim_np.items()}, now_np[g:g + 1],
+                (None, None), np.asarray([grid[g].serving.n_reqs]),
+                grid[g])
+            for k in SERVE_STAT_KEYS:
+                res[k] = int(serve_np[k][g])
+            res["n_steps"] = n_steps
+            # derived serving scalars come from the same registry table
+            # the reduce path applies — one formula source (DESIGN.md §13)
+            metrics_lib.finalize_scalars(res)
+            if ys_np is not None:
+                res["steps"] = {"occ": ys_np[0][g], "qlen": ys_np[1][g],
+                                "arrivals": ys_np[2][g]}
+            out_rows.append(res)
     return out_rows
 
 
