@@ -27,6 +27,13 @@ performance difference between the two is one of our reproduced claims
 
 All state lives in small arrays, so the structure ``vmap``s across
 channels / configurations and runs inside ``lax.scan`` simulator steps.
+The tables are stored way-major, ``[ways, sets]``: under ``vmap`` the
+lanes lead and the set axis is the minor one, so it fills the TPU's
+128-lane tile.  Set-major ``[sets, ways]`` tables would put the 2 ways
+on the lane axis, padded 64x, and the compiler copies a table into that
+padded form for every row read of every scan step.  Reads and writes
+address single ``(way, set)`` elements, one per way (``_row``,
+``.at[way, set]``), so both want the one layout.
 
 Static shape vs traced params (DESIGN.md §4): every operation takes an
 ``HCRACConfig`` — the *static* part, fixing array shapes (``n_sets`` /
@@ -71,9 +78,9 @@ class HCRACConfig:
 
 
 class HCRACState(NamedTuple):
-    tags: jnp.ndarray     # [sets, ways] int32 global row id (NO_TAG = empty)
-    itime: jnp.ndarray    # [sets, ways] int32 insertion cycle
-    lru: jnp.ndarray      # [sets, ways] int32 last-touch cycle (LRU policy)
+    tags: jnp.ndarray     # [ways, sets] int32 global row id (NO_TAG = empty)
+    itime: jnp.ndarray    # [ways, sets] int32 insertion cycle
+    lru: jnp.ndarray      # [ways, sets] int32 last-touch cycle (LRU policy)
 
 
 class HCRACParams(NamedTuple):
@@ -97,7 +104,7 @@ def params_of(cfg: HCRACConfig) -> HCRACParams:
 
 
 def init(cfg: HCRACConfig) -> HCRACState:
-    shape = (cfg.n_sets, cfg.n_ways)
+    shape = (cfg.n_ways, cfg.n_sets)
     return HCRACState(
         tags=jnp.full(shape, NO_TAG, jnp.int32),
         itime=jnp.zeros(shape, jnp.int32),
@@ -123,25 +130,36 @@ def _alive(cfg: HCRACConfig, set_idx, itime, t, params: HCRACParams = None):
     return (t - phase) // c == (itime - phase) // c
 
 
+def _row(cfg: HCRACConfig, table, set_idx):
+    """The entries of set(s) ``set_idx`` in every way, ``[ways,
+    *set_idx.shape]``: one read of single elements per way, which leaves
+    the table in the layout its writes want.  Slicing the whole ``[ways]``
+    column (``table[:, set_idx]``) makes the compiler put the way axis
+    minor for the read and relayout the table to get there."""
+    return jnp.stack([table[w, set_idx] for w in range(cfg.n_ways)])
+
+
 def lookup(cfg: HCRACConfig, st: HCRACState, gid, t, enable=True,
            params: HCRACParams = None):
     """Look up global row id ``gid`` at cycle ``t``.
 
-    Returns ``(hit, new_state)``; a hit refreshes the entry's LRU stamp
-    (and — since the row is about to be activated, i.e. recharged — its
-    insertion time, matching the controller re-arming the entry).
-    ``enable`` masks the LRU side effect (the returned ``hit`` is
+    Returns ``(hit, new_state)``; a hit refreshes the matching entry's LRU
+    stamp.  ``enable`` masks the LRU side effect (the returned ``hit`` is
     unmasked — callers combine it with their own predicates).
     """
     p = params if params is not None else params_of(cfg)
     set_idx = jnp.mod(gid, p.n_sets).astype(jnp.int32)
-    row_tags = st.tags[set_idx]            # [ways]
-    row_itime = st.itime[set_idx]
+    row_tags = _row(cfg, st.tags, set_idx)  # [ways]
+    row_itime = _row(cfg, st.itime, set_idx)
     valid = (row_tags != NO_TAG) & _alive(cfg, set_idx, row_itime, t, p)
     match = valid & (row_tags == gid)
     hit = jnp.any(match)
-    new_lru = jnp.where(match & jnp.asarray(enable), t, st.lru[set_idx])
-    st = st._replace(lru=st.lru.at[set_idx].set(new_lru))
+    new_lru = jnp.where(match & jnp.asarray(enable), t,
+                        _row(cfg, st.lru, set_idx))
+    lru = st.lru
+    for w in range(cfg.n_ways):
+        lru = lru.at[w, set_idx].set(new_lru[w])
+    st = st._replace(lru=lru)
     return hit, st
 
 
@@ -155,9 +173,9 @@ def insert(cfg: HCRACConfig, st: HCRACState, gid, t, enable=True,
     """
     p = params if params is not None else params_of(cfg)
     set_idx = jnp.mod(gid, p.n_sets).astype(jnp.int32)
-    row_tags = st.tags[set_idx]
-    row_itime = st.itime[set_idx]
-    row_lru = st.lru[set_idx]
+    row_tags = _row(cfg, st.tags, set_idx)
+    row_itime = _row(cfg, st.itime, set_idx)
+    row_lru = _row(cfg, st.lru, set_idx)
     valid = (row_tags != NO_TAG) & _alive(cfg, set_idx, row_itime, t, p)
     match = valid & (row_tags == gid)
 
@@ -169,18 +187,11 @@ def insert(cfg: HCRACConfig, st: HCRACState, gid, t, enable=True,
                     jnp.where(any_inv, inv_way, lru_way)).astype(jnp.int32)
 
     en = jnp.asarray(enable)
-    new_tags = st.tags.at[set_idx, way].set(jnp.where(en, gid, row_tags[way]))
-    new_itime = st.itime.at[set_idx, way].set(
+    new_tags = st.tags.at[way, set_idx].set(jnp.where(en, gid, row_tags[way]))
+    new_itime = st.itime.at[way, set_idx].set(
         jnp.where(en, t, row_itime[way]))
-    new_lru = st.lru.at[set_idx, way].set(jnp.where(en, t, row_lru[way]))
+    new_lru = st.lru.at[way, set_idx].set(jnp.where(en, t, row_lru[way]))
     return HCRACState(tags=new_tags, itime=new_itime, lru=new_lru)
-
-
-def occupancy(cfg: HCRACConfig, st: HCRACState, t) -> jnp.ndarray:
-    """Fraction of currently-alive entries (diagnostic)."""
-    sets = jnp.arange(cfg.n_sets, dtype=jnp.int32)[:, None]
-    valid = (st.tags != NO_TAG) & _alive(cfg, sets, st.itime, t)
-    return jnp.mean(valid.astype(jnp.float32))
 
 
 def padded_shape(cfg: HCRACConfig, n_sets_max: int) -> HCRACConfig:

@@ -48,32 +48,33 @@ def _hcrac_kernel(set_ref, gid_ref, t_ref, tags_ref, lo_ref, hi_ref,
     hit_ref[...] = jnp.max(hit, axis=1, keepdims=True)
 
 
-def _live_interval(cfg: HCRACConfig, tags, itime):
+def _live_interval(cfg: HCRACConfig, itime):
     """Per-entry ``[lo, hi)`` interval of lookup times at which the entry
-    is alive, ``[W, S]`` each (transposed: lanes run over sets)."""
+    is alive, ``[W, S]`` each (lanes run over sets)."""
     c = jnp.int32(cfg.caching_cycles)
     if cfg.exact_expiry:
         # (t - itime) <= C
         lo = jnp.full(itime.shape, _I32_MIN, jnp.int32)
         hi = itime + c + 1
     else:
-        S, W = tags.shape
-        slot = (jnp.arange(S, dtype=jnp.int32)[:, None] * W
-                + jnp.arange(W, dtype=jnp.int32)[None, :])
+        W, S = itime.shape
+        slot = (jnp.arange(S, dtype=jnp.int32)[None, :] * W
+                + jnp.arange(W, dtype=jnp.int32)[:, None])
         phase = (slot + 1) * jnp.int32(cfg.sweep_period)
         lo = phase + ((itime - phase) // c) * c
         hi = lo + c
-    return lo.T, hi.T
+    return lo, hi
 
 
 def hcrac_lookup_kernel(cfg: HCRACConfig, tags, itime, gids, times, *,
                         block_q: int = 256, interpret: bool = False):
-    """tags/itime: [S, W]; gids/times: [Q] -> hits [Q] int32."""
+    """tags/itime: [W, S] (the HCRAC's own layout); gids/times: [Q] ->
+    hits [Q] int32."""
     Q = gids.shape[0]
     block_q = min(block_q, Q)
     assert Q % block_q == 0
-    S, W = tags.shape
-    lo, hi = _live_interval(cfg, tags, itime)
+    W, S = tags.shape
+    lo, hi = _live_interval(cfg, itime)
     col = lambda x: x.astype(jnp.int32).reshape(Q, 1)
     probe = pl.BlockSpec((block_q, 1), lambda i: (i, 0))
     table = pl.BlockSpec((W, S), lambda i: (0, 0))
@@ -84,5 +85,5 @@ def hcrac_lookup_kernel(cfg: HCRACConfig, tags, itime, gids, times, *,
         out_specs=probe,
         out_shape=jax.ShapeDtypeStruct((Q, 1), jnp.int32),
         interpret=interpret,
-    )(col(jnp.mod(gids, cfg.n_sets)), col(gids), col(times), tags.T, lo, hi)
+    )(col(jnp.mod(gids, cfg.n_sets)), col(gids), col(times), tags, lo, hi)
     return hits[:, 0]

@@ -20,6 +20,10 @@ import numpy as np
 from repro.core import hcrac as hcl
 from repro.core.timing import ms_to_cycles
 
+#: ``touch`` inserts page by page: one compiled insert per table shape
+#: instead of an eager dispatch of every op of every insert
+_insert = jax.jit(hcl.insert, static_argnums=0)
+
 
 @dataclasses.dataclass
 class HotPageConfig:
@@ -64,8 +68,8 @@ class HotPageTracker:
         """Record accesses (insert/refresh entries)."""
         st = self.state
         for g in np.asarray(page_ids, np.int32):
-            st = hcl.insert(self.hc_cfg, st, jnp.int32(g),
-                            jnp.int32(now_cycles))
+            st = _insert(self.hc_cfg, st, jnp.int32(g),
+                         jnp.int32(now_cycles))
         self.state = st
 
     def page_to_dram(self, page_ids: np.ndarray):

@@ -157,8 +157,8 @@ def _probe_many(hshape: hcl.HCRACConfig, st: hcl.HCRACState, gids, t,
     """Batched read-only hot-table lookup (no LRU side effect) — the
     vectorized form of ``hcrac.lookup(..., enable=False)``."""
     set_idx = jnp.mod(gids, p.n_sets).astype(jnp.int32)      # [N]
-    tags = st.tags[set_idx]                                  # [N, W]
-    itime = st.itime[set_idx]
+    tags = hcl._row(hshape, st.tags, set_idx).T              # [N, W]
+    itime = hcl._row(hshape, st.itime, set_idx).T
     alive = hcl._alive(hshape, set_idx[:, None], itime, t, p)
     return jnp.any((tags != hcl.NO_TAG) & alive
                    & (tags == gids[:, None]), axis=1)

@@ -5,11 +5,13 @@ lookup may hit on an entry older than the caching duration* — the
 mechanism's safety property (a stale hit would under-time a leaky row).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypo import given, settings, st  # hypothesis, or deterministic fallback
 
+from repro.controller.oracle import _HostHCRAC
 from repro.core import hcrac as H
 
 CFG = H.HCRACConfig(n_entries=32, n_ways=2, caching_cycles=1000)
@@ -92,6 +94,53 @@ def test_sweep_alive_implies_within_duration(itime, dt, set_idx):
                  jnp.int32(t))).any())
     if alive:
         assert t - itime <= cfg.caching_cycles
+
+
+@pytest.mark.parametrize("exact", [False, True], ids=["sweep", "exact"])
+@pytest.mark.parametrize("n_sets,n_ways", [(4, 1), (8, 2), (16, 4)])
+def test_matches_numpy_oracle_step_by_step(n_sets, n_ways, exact):
+    """A random insert/lookup sequence against ``controller/oracle.py``'s
+    numpy HCRAC: every hit, and after every operation the whole table
+    read back through the way-major ``[ways, sets]`` layout.  Few row ids
+    and jumps past the duration give expired entries in several ways of a
+    set (first-invalid ties); inserts at the same cycle give equal LRU
+    stamps (lowest-way ties); masked inserts must change nothing."""
+    cfg = H.HCRACConfig(n_entries=n_sets * n_ways, n_ways=n_ways,
+                        caching_cycles=400, exact_expiry=exact)
+    insert = jax.jit(H.insert, static_argnums=0)
+    lookup = jax.jit(H.lookup, static_argnums=0)
+    rng = np.random.default_rng(n_sets * 10 + n_ways + 100 * exact)
+    st_, ref = H.init(cfg), _HostHCRAC(cfg)
+    t = s = 0
+    kinds = {"first_invalid_tie": 0, "lru_tie": 0}
+    for _ in range(400):
+        t += 450 if rng.random() < 0.02 else int(rng.choice([0, 0, 1, 2]))
+        gid = int(rng.integers(0, (n_ways + 1) * n_sets))
+        if rng.random() < 0.5:                   # stay in the last set
+            gid = s + n_sets * int(rng.integers(0, n_ways + 1))
+        s = gid % n_sets
+        if rng.random() < 0.6:
+            en = bool(rng.random() < 0.9)
+            valid = ref._valid(s, t)
+            if en and not (valid & (ref.tags[s] == gid)).any():
+                if (~valid).sum() > 1:
+                    kinds["first_invalid_tie"] += 1
+                elif valid.all() and (ref.lru[s] == ref.lru[s].min()).sum() > 1:
+                    kinds["lru_tie"] += 1
+            st_ = insert(cfg, st_, jnp.int32(gid), jnp.int32(t),
+                         enable=jnp.bool_(en))
+            ref.insert(gid, t, enable=en)
+        else:
+            hit, st_ = lookup(cfg, st_, jnp.int32(gid), jnp.int32(t))
+            assert bool(hit) == ref.lookup(gid, t)
+        assert st_.tags.shape == (n_ways, n_sets)
+        for got, want in ((st_.tags, ref.tags), (st_.itime, ref.itime),
+                          (st_.lru, ref.lru)):
+            np.testing.assert_array_equal(np.asarray(got), want.T)
+        np.testing.assert_array_equal(
+            np.asarray(H._row(cfg, st_.tags, jnp.int32(s))), ref.tags[s])
+    if n_ways > 1:
+        assert min(kinds.values()) > 0, kinds
 
 
 def test_storage_cost_matches_thesis():

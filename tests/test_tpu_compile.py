@@ -9,6 +9,7 @@ and the persistent compilation cache is off for these compiles.
 """
 
 import dataclasses
+import re
 
 import jax
 import numpy as np
@@ -92,6 +93,79 @@ def test_run_batched_compiles(one_chip, trace_launch):
     assert exe.memory_analysis() is not None
 
 
+def _while_bodies(hlo: str) -> dict[str, list[str]]:
+    """Instruction lines of each while-loop body computation in an HLO
+    module's text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    return {b: comps[b]
+            for b in set(re.findall(r"body=%?([\w.\-]+)", hlo))}
+
+
+def _tiled_bytes(dims, layout: str) -> int:
+    """Bytes of an s32 array under a TPU layout ``{minor..major:T(a,b)}``:
+    the tile pads the minor dimensions up to its shape."""
+    m2m = [int(d) for d in layout.split(":")[0].split(",")]
+    tile = re.search(r"T\(([\d,]+)\)", layout)
+    padded = list(dims)
+    if tile:
+        t = [int(x) for x in tile.group(1).split(",")]
+        for dim, size in zip(m2m, reversed(t)):
+            padded[dim] = -(-padded[dim] // size) * size
+    return 4 * int(np.prod(padded))
+
+
+def test_run_grid_hcrac_tables_stay_in_one_unpadded_layout(one_chip):
+    """The ``inorder.mix8_rltl`` benchmark cell's launch shape (4 eight-core
+    traces x 15 points, a 4,096-entry HCRAC envelope, 200 requests per
+    core): the scan body must neither copy an HCRAC table nor
+    hold one in a layout whose tiles pad it beyond 2x.  Set-major
+    ``[sets, ways]`` tables put the 2 ways on the 128-lane axis (64x) and
+    the compiler copied each table into that form for every row read."""
+    caps = (512, 1024, 2048, 4096)
+    grid = ([_thesis_cfg("base")]
+            + [dataclasses.replace(c, mech=dataclasses.replace(
+                c.mech, hcrac=dataclasses.replace(c.mech.hcrac, n_entries=n)))
+               for k in ("chargecache", "cc_nuat", "rltl")
+               for c in [_thesis_cfg(k)] for n in caps]
+            + [_thesis_cfg("nuat"), _thesis_cfg("lldram")])
+    shape, stacked = sim_mod._grid_shape_and_params(grid, grid)
+    ns_geoms, ns_idx = sim_mod._hoist_geoms(grid, grid)
+    batches = [multicore_batch(mix, 200, seed=5 + i)
+               for i, mix in enumerate(random_mixes(4, 8))]
+    traces = jax.tree_util.tree_map(
+        lambda *xs: np.stack(xs), *[sim_mod._device_trace(b) for b in batches])
+    n_steps = int(batches[0].gap.size)
+    S, W = shape.hcrac.n_sets, shape.hcrac.n_ways
+    assert (len(grid), S, W) == (15, 2048, 2)
+    dyn = _specs((stacked, traces, np.zeros(4, np.int32), ns_geoms, ns_idx),
+                 one_chip)
+    hlo = _compile(jax.jit(lambda p, tr, wu, g, gi: sim_mod._run_grid(
+        shape, p, tr, wu, n_steps, True, g, gi)), *dyn).as_text()
+    table = sorted((4, 15, S, W))
+    seen = 0
+    for body, lines in _while_bodies(hlo).items():
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = s32\[([\d,]+)\]"
+                         r"\{([^}]*)\} ([\w-]+)\(", line)
+            if not m:
+                continue
+            name, dims, layout, op = m.groups()
+            dims = [int(d) for d in dims.split(",")]
+            if sorted(dims) != table:
+                continue
+            seen += 1
+            assert op != "copy", f"{body}: HCRAC table copy {name}"
+            assert _tiled_bytes(dims, layout) <= 2 * 4 * np.prod(dims), (
+                f"{body}: {name} s32{dims}{{{layout}}} pads the table")
+    assert seen, "no HCRAC-table-shaped buffer found in any scan body"
+
+
 def test_rltl_hist_device_compiles(one_chip, trace_launch):
     """The device sort compiles in ~7 s at 512 events per point and
     ~30 s at 3,200, so this one runs on a short event stream."""
@@ -152,7 +226,7 @@ def test_hcrac_lookup_kernel_compiles(one_chip, exact):
     kernel on the chip (``tpu_custom_call``), at the 8-core table size."""
     from repro.kernels.hcrac.kernel import hcrac_lookup_kernel
     cfg = HCRACConfig(n_entries=1024, exact_expiry=exact)
-    table = jax.ShapeDtypeStruct((cfg.n_sets, cfg.n_ways), np.int32,
+    table = jax.ShapeDtypeStruct((cfg.n_ways, cfg.n_sets), np.int32,
                                  sharding=one_chip)
     probes = jax.ShapeDtypeStruct((512,), np.int32, sharding=one_chip)
     exe = _compile(jax.jit(lambda t, it, g, ts: hcrac_lookup_kernel(
