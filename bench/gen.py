@@ -4,10 +4,13 @@ A copy of the statistical trace model the simulator's studies are driven
 by: the 22 ``*_like`` profiles (SPEC CPU2006 / TPC / STREAM stand-ins),
 one core's stream (``generate_trace``), the padded multi-core batch with
 its closed-row queue-hit lookahead (``multicore_batch``) and the thesis's
-random eight-core mixes (``random_mixes``).  It is kept here, apart from
-the program, so a change to the program's generator cannot move the
-benchmark's traffic; ``bench/tests/test_bench.py`` checks that the copy
-still reproduces the program's generator bitwise.
+random eight-core mixes (``random_mixes``).  The bank count and the rows
+per bank are the configuration's (``study.build_study`` passes them), so
+a memory system of another geometry gets streams that span all of its
+banks.  It is kept here, apart from the program, so a change to the
+program's generator cannot move the benchmark's traffic;
+``bench/tests/test_bench.py`` checks that the copy still reproduces the
+program's generator bitwise.
 
 Numpy only: worker processes import this module to build studies in
 parallel, and must never load JAX.
@@ -19,12 +22,6 @@ import dataclasses
 from typing import NamedTuple
 
 import numpy as np
-
-#: Table 5.1 geometry the traces are generated for: 2 channels x 1 rank
-#: x 8 banks, 65,536 rows per bank
-N_BANKS_TOTAL = 16
-N_ROWS = 65536
-
 
 @dataclasses.dataclass(frozen=True)
 class Profile:
@@ -108,13 +105,15 @@ class Batch(NamedTuple):
     length: np.ndarray
 
 
-def generate_trace(profile: Profile, n_req: int, seed: int,
-                   row_base: int = 0, row_span: int | None = None):
-    """One core's stream: ``(gap, bank, row, is_write, dep)`` arrays."""
+def generate_trace(profile: Profile, n_req: int, seed: int, n_banks: int,
+                   n_rows: int, row_base: int = 0,
+                   row_span: int | None = None):
+    """One core's stream over ``n_banks`` banks in all and ``n_rows``
+    rows per bank: ``(gap, bank, row, is_write, dep)`` arrays."""
     n_req = max(8, int(n_req * profile.traffic))
     rng = np.random.default_rng(seed)
-    span = row_span or N_ROWS
-    nb = N_BANKS_TOTAL
+    span = row_span or n_rows
+    nb = n_banks
 
     gap = rng.geometric(1.0 / max(profile.mean_gap, 1.001),
                         n_req).astype(np.int32)
@@ -176,12 +175,14 @@ def _next_same(bank, row) -> np.ndarray:
     return out
 
 
-def multicore_batch(names, n_req: int, seed: int = 0) -> Batch:
-    """A multiprogrammed mix: core ``i`` draws from seed
-    ``seed * 1000 + i`` inside its own slice of the row space."""
-    span = N_ROWS // max(len(names), 1)
-    cores = [generate_trace(BY_NAME[n], n_req, seed * 1000 + i,
-                            row_base=i * span, row_span=span)
+def multicore_batch(names, n_req: int, seed: int, n_banks: int,
+                    n_rows: int) -> Batch:
+    """A multiprogrammed mix over ``n_banks`` banks in all and ``n_rows``
+    rows per bank: core ``i`` draws from seed ``seed * 1000 + i`` inside
+    its own slice of the row space."""
+    span = n_rows // max(len(names), 1)
+    cores = [generate_trace(BY_NAME[n], n_req, seed * 1000 + i, n_banks,
+                            n_rows, row_base=i * span, row_span=span)
              for i, n in enumerate(names)]
     lengths = np.array([len(c[0]) for c in cores], np.int32)
     c, L = len(cores), int(lengths.max())

@@ -4,10 +4,13 @@
 seed, warms up the cell's own shapes with a study no timed study shares,
 runs studies back to back through ``Experiment.run()`` for the measured
 window, and checks a seeded sample of what the window produced against
-the plain reference (``reference.py``).  Everything a cell is made of is
-found by name: its configuration (``configs/<config>.json``), its traffic
-(``traffic/<traffic>.json``) and each per-layer metric
-(``metrics/<metric>.py``), all listed in ``BENCHMARK.json``.
+the configuration's plain reference (``check.py``).  Everything a cell is
+made of is found by name: its configuration (``configs/<config>.json``,
+whose ``geometry`` also shapes the streams and whose optional
+``"reference"`` names its reference module), its traffic
+(``traffic/<traffic>.json``, with an optional ``"reduce"`` list of
+streamed metrics) and each per-layer metric (``metrics/<metric>.py``),
+all listed in ``BENCHMARK.json``.
 
 JAX is imported only inside ``run_cell``: the study-building worker
 processes import this package too and must never touch the chip.
@@ -81,13 +84,20 @@ def load_reader(metric: str):
     return mod.read
 
 
+#: chips a cell asks for -> (visible chips, chips-per-process bounds)
+_VISIBLE = {1: ("0", "1,1,1"), 4: ("0,1,2,3", "2,2,1")}
+
+
 def limit_visible_chips(chips: int) -> None:
     """On a host with more chips than the cell asks for, show JAX only
-    the first ``chips`` (set before JAX starts)."""
+    the first ``chips`` (set before JAX starts): the program shards a
+    grid over every device it sees."""
     present = len(glob.glob("/dev/accel*")) or len(glob.glob("/dev/vfio/[0-9]*"))
-    if chips == 1 and present > 1 and "TPU_VISIBLE_CHIPS" not in os.environ:
-        os.environ.update(TPU_VISIBLE_CHIPS="0",
-                          TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+    if (chips in _VISIBLE and present > chips
+            and "TPU_VISIBLE_CHIPS" not in os.environ):
+        visible, bounds = _VISIBLE[chips]
+        os.environ.update(TPU_VISIBLE_CHIPS=visible,
+                          TPU_CHIPS_PER_PROCESS_BOUNDS=bounds,
                           TPU_PROCESS_BOUNDS="1,1,1")
 
 
@@ -144,7 +154,8 @@ class StudyPool:
 
 def program_experiment_kwargs(cfg: dict, traffic: dict):
     """The cell as the program's user writes it: the base ``SimConfig``
-    and the ``Experiment`` axes and options (program API only)."""
+    and the ``Experiment`` axes and options, ``reduce`` among them where
+    the traffic streams metrics (program API only)."""
     from repro.core import HCRACConfig, MechanismConfig, SimConfig
     from repro.core.dram import DRAMConfig
     from repro.core.timing import TimingParams
@@ -171,7 +182,10 @@ def program_experiment_kwargs(cfg: dict, traffic: dict):
             axes["capacity"] = [(v, v * cfg["cores"]) for v in values]
         else:
             axes[name] = list(values)
-    return {"axes": axes, "base": base, "rltl": bool(traffic["rltl"])}
+    kw = {"axes": axes, "base": base, "rltl": bool(traffic["rltl"])}
+    if traffic.get("reduce"):
+        kw["reduce"] = tuple(traffic["reduce"])
+    return kw
 
 
 def run_study(batches, kw):

@@ -18,8 +18,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import check
 import gen
-import reference
 
 
 def study_rng(seed: int, k: int) -> np.random.Generator:
@@ -47,10 +47,19 @@ def study_mixes(traffic: dict, cores: int, seed: int, k: int):
     return mixes, seeds
 
 
+def stream_geometry(cfg: dict) -> tuple[int, int]:
+    """The banks in all (channels x ranks x banks per rank) and the rows
+    per bank that the configuration's streams address."""
+    g = cfg["geometry"]
+    return g["n_channels"] * g["n_ranks"] * g["n_banks"], g["n_rows"]
+
+
 def build_study(traffic: dict, cfg: dict, seed: int, k: int):
     """The host streams of study ``k``: one padded batch per mix."""
     mixes, seeds = study_mixes(traffic, cfg["cores"], seed, k)
-    return [gen.multicore_batch(m, cfg["requests_per_core"], seed=s)
+    n_banks, n_rows = stream_geometry(cfg)
+    return [gen.multicore_batch(m, cfg["requests_per_core"], s, n_banks,
+                                n_rows)
             for m, s in zip(mixes, seeds)]
 
 
@@ -81,19 +90,28 @@ def work_of(batches, n_points: int) -> int:
 
 def check_sample(traffic: dict, cfg: dict, seed: int, n_studies: int):
     """The (study, mix, grid point) triples a run compares with the
-    reference, drawn from the run seed: ``check_points`` of them (one
-    per compared mechanism at least), cycling through the grid's
-    mechanisms that the reference models and through the mixes in a
-    shuffled order, so the sample spans every such mechanism and as many
-    distinct mixes as it has points; the study and the value of every
-    other axis are drawn per point."""
+    configuration's reference (``check.load_reference``), drawn from the
+    run seed: ``check_points`` of them (one per compared mechanism at
+    least), cycling through the grid's mechanisms that the reference
+    models and through the mixes in a shuffled order, so the sample
+    spans every such mechanism and as many distinct mixes as it has
+    points; the study and the value of every other axis are drawn per
+    point.  Where the traffic sets ``"check_every_point"``, the sample is
+    instead every grid point of a modelled mechanism once, in a shuffled
+    order, each on the next mix of the shuffled mixes and in a drawn
+    study, so a fault confined to any of the grid's lanes is seen."""
     rng = np.random.default_rng(np.random.SeedSequence([seed % 2 ** 64,
                                                         2 ** 32 + 7]))
     pts = grid_points(traffic, cfg)
-    mechs = [m for m in traffic["axes"]["mechanism"]
-             if m in reference.MECHANISMS]
+    modelled = check.load_reference(cfg).MECHANISMS
+    mechs = [m for m in traffic["axes"]["mechanism"] if m in modelled]
     n_mixes = traffic["mixes_per_study"]
     mix_order = rng.permutation(n_mixes)
+    if traffic.get("check_every_point"):
+        compared = [j for j, (p, _) in enumerate(pts)
+                    if p["mechanism"] in modelled]
+        return [(int(rng.integers(0, n_studies)), int(mix_order[i % n_mixes]),
+                 int(j)) for i, j in enumerate(rng.permutation(compared))]
     out = []
     for i in range(max(traffic.get("check_points", 0), len(mechs))):
         cand = [j for j, (p, _) in enumerate(pts)

@@ -7,7 +7,8 @@ tier-1 suite; run with ``python -m pytest bench/tests``).
 * without a TPU the command exits non-zero and prints nothing;
 * the trace reduction reads a small recorded chip trace;
 * the control (the program's legacy refresh model) is caught;
-* faults planted under the timed path make ``correct`` false.
+* faults planted under the timed path make ``correct`` false: answers
+  altered, either half of the grid lanes or half of the mixes left out.
 """
 
 from __future__ import annotations
@@ -57,7 +58,7 @@ def run_tiny(name, seed=2 ** 31 + 99, seconds=0.5):
 def test_generator_copy_matches_program(seed):
     from repro.core import traces
     names = gen.random_mixes(1, 8, seed=seed % 1000)[0]
-    ours = gen.multicore_batch(names, 300, seed=seed)
+    ours = gen.multicore_batch(names, 300, seed, 16, 65536)
     theirs = traces.multicore_batch(names, 300, seed=seed)
     for f in ours._fields:
         np.testing.assert_array_equal(getattr(ours, f), getattr(theirs, f))
@@ -151,22 +152,46 @@ drain, launch = sim._drain_grid, sim._launch_grid
 
 def altered(out, grid, batches, n_batch, reduce_keys=None):
     rows = drain(out, grid, batches, n_batch, reduce_keys)
+    if reduce_keys is not None:   # streamed: the [batch, grid, deps] ints
+        return rows + 1
     for row in rows:
         for cell in row:
             cell["lat_sum"] = cell["lat_sum"] + 1
     return rows
 
 def half(shape, stacked, *a, **k):
+    # the second half of the grid lanes runs the first half's points
     import jax
     n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
     pick = np.arange(n) % ((n + 1) // 2)
     stacked = jax.tree_util.tree_map(lambda x: np.asarray(x)[pick], stacked)
     return launch(shape, stacked, *a, **k)
 
+def half_first(shape, stacked, *a, **k):
+    # the first half of the grid lanes runs the second half's points
+    import jax
+    n = jax.tree_util.tree_leaves(stacked)[0].shape[0]
+    pick = np.arange(n)
+    pick[:n // 2] = pick[n - n // 2:]
+    stacked = jax.tree_util.tree_map(lambda x: np.asarray(x)[pick], stacked)
+    return launch(shape, stacked, *a, **k)
+
+def half_batch(shape, stacked, traces, warmups, *a, **k):
+    # the second half of the mixes runs the first half's streams
+    import jax
+    n = len(warmups)
+    pick = np.arange(n) % ((n + 1) // 2)
+    traces = jax.tree_util.tree_map(lambda x: np.asarray(x)[pick], traces)
+    return launch(shape, stacked, traces, np.asarray(warmups)[pick], *a, **k)
+
 if fault == "altered":
     sim._drain_grid = altered
-else:
+elif fault == "half":
     sim._launch_grid = half
+elif fault == "half_first":
+    sim._launch_grid = half_first
+else:
+    sim._launch_grid = half_batch
 out = harness.run_cell({name!r}, 1234567, 0.5, False, require_tpu=False,
                        cfg_override={cfg!r}, traffic_override={traffic!r},
                        workers=0, log=lambda m: None)
@@ -175,7 +200,8 @@ print(json.dumps({{"correct": out["correct"], "checks": out["checks"]}}))
 
 
 @pytest.mark.parametrize("name,fault", [
-    (c, f) for c in CELLS for f in ("altered", "half")])
+    (c, f) for c in CELLS
+    for f in ("altered", "half", "half_first", "half_batch")])
 def test_fault_makes_run_incorrect(name, fault):
     traffic = tiny_traffic(name)
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
