@@ -78,6 +78,10 @@ class WindowState(NamedTuple):
     # controller clock + admission counter (scalars)
     now: jnp.ndarray   # decision horizon: requests issued <= now admit
     seq: jnp.ndarray
+    # newest ACT cycle (running max) per (rank, bank group) slot
+    # (``dram.bank_group_slot``), for tRRD_L: present only on the
+    # bank-group path (DESIGN.md §16), else no carry leaf at all
+    bg_last_act: jnp.ndarray | None = None  # [NR]
 
 
 def _init_window(shape: SimShape, n_cores: int, max_len: int,
@@ -98,6 +102,8 @@ def _init_window(shape: SimShape, n_cores: int, max_len: int,
         faw_ring=jnp.full((nr, FAW_DEPTH), NEG, jnp.int32),
         faw_ptr=jnp.zeros((nr,), jnp.int32),
         now=jnp.int32(0), seq=jnp.int32(0),
+        bg_last_act=(jnp.full((nr,), NEG, jnp.int32)
+                     if shape.envelope.max_bank_groups > 1 else None),
     )
 
 
@@ -114,6 +120,7 @@ def _make_window_step(shape: SimShape, W: int, p: MechParams, trace: dict,
     mshr = shape.mshr
     T = p.timing
     cores = jnp.arange(n_cores)
+    bg_path = shape.envelope.max_bank_groups > 1
 
     def admit_one(_, ws: WindowState) -> WindowState:
         """Try to admit one request: the earliest-issue eligible core's
@@ -204,10 +211,18 @@ def _make_window_step(shape: SimShape, W: int, p: MechParams, trace: dict,
             ws.rank_last_act[rank] + T.tRRD,
             ws.faw_ring[rank, ws.faw_ptr[rank]] + T.tFAW)
         floor = jnp.where(p.frfcfs, floor, 0)
+        floor_bg = None
+        if bg_path:
+            # tRRD_L: ACT to ACT within one bank group of the rank
+            g_slot = dram_lib.bank_group_slot(p.geom, bi)
+            floor_bg = jnp.where(
+                p.frfcfs,
+                jnp.maximum(floor, ws.bg_last_act[g_slot] + T.tRRD_L), 0)
 
         st2, done, events, (t_act, needs_act) = sim_mod._service(
             shape, p, st, t_arr, bi, ws.w_row[e], ws.w_write[e],
-            ws.w_ns[e], measure, alive, act_floor=floor)
+            ws.w_ns[e], measure, alive, act_floor=floor,
+            act_floor_bg=floor_bg)
 
         # 4. rank window update (real ACTs of frfcfs points only).  The
         # running max keeps the register monotone even when an old miss
@@ -256,6 +271,10 @@ def _make_window_step(shape: SimShape, W: int, p: MechParams, trace: dict,
                           jnp.maximum(ws.now, sim3.cmd_bus_free[ch]),
                           ws.now),
         )
+        if bg_path:
+            ws = ws._replace(bg_last_act=ws.bg_last_act.at[g_slot].set(
+                jnp.where(upd, jnp.maximum(ws.bg_last_act[g_slot], t_act),
+                          ws.bg_last_act[g_slot])))
         return ws, (events if collect_events else None)
 
     return step

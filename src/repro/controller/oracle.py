@@ -22,9 +22,12 @@ Two deliberate sharing decisions (ISSUE: "same timing tables"):
   must model independently.
 
 Everything else — the refresh catch-up, the PRE/ACT/RDWR/auto-PRE
-chain, bus accounting, the FR-FCFS selection key and the per-rank
-tRRD/tFAW windows — is an independent transliteration of the protocol
-in plain python integers (no jax in the decision loop).
+chain, bus accounting, the FR-FCFS selection key, the per-rank
+tRRD/tFAW windows and the bank-group rules (tCCD_S/tCCD_L on both
+tiers, tRRD_L on the FR-FCFS tier; bank ``b`` of a rank in group
+``b mod n_bank_groups``, DESIGN.md §16) — is an independent
+transliteration of the protocol in plain python integers (no jax in the
+decision loop).
 
 The oracle is *event-driven with exact cycle stamping*: it steps from
 scheduling decision to scheduling decision rather than cycle by cycle,
@@ -43,7 +46,6 @@ import numpy as np
 from repro.core import mechanisms as registry
 from repro.core import simulator as sim_mod
 from repro.core.simulator import INF, SimConfig
-from repro.core.timing import ms_to_cycles
 from repro.controller.engine import FAW_DEPTH, HIT_PENALTY, NEG
 
 NO_ROW = -1
@@ -154,7 +156,10 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
     n_rows = int(D.n_rows)
     bpc = int(D.banks_per_channel)
     nch = int(D.n_channels)
-    ms8 = int(ms_to_cycles(8.0))
+    ms8 = int(T.cycles_8ms)
+    n_banks = int(D.n_banks)
+    n_bg = int(D.n_bank_groups)
+    bank_groups = n_bg > 1
 
     # mechanism timing tables: the engine's own traced blocks, consulted
     # eagerly per request (registration-order fold, identical values)
@@ -199,10 +204,20 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
     rank_last_act = [int(NEG)] * n_ranks_g
     faw_ring = [[int(NEG)] * FAW_DEPTH for _ in range(n_ranks_g)]
     faw_ptr = [0] * n_ranks_g
+    # bank-group registers: newest RD/WR per channel and per (rank,
+    # group) slot, newest ACT per (rank, group) slot
+    last_cas = [-int(INF)] * nch
+    last_cas_bg = [-int(INF)] * nb
+    bg_last_act = [int(NEG)] * nb
     window: list[_Entry] = []
     now = 0
     seq = 0
-    stats = {k: 0 for k in sim_mod.STAT_KEYS}
+    stats = {k: 0 for k in sim_mod.STAT_KEYS + sim_mod.BG_STAT_KEYS}
+
+    def group_slot(b):
+        """The (rank, bank group) register slot: the rank's first bank
+        id plus ``(bank within rank) mod n_bank_groups``."""
+        return b - b % n_banks + (b % n_banks) % n_bg
 
     def radj(t, row):
         """Legacy closed-form refresh blackout (dram.refresh_adjust)."""
@@ -257,7 +272,7 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
         seq += 1
         return True
 
-    def service(ent: _Entry, measure: bool, floor: int):
+    def service(ent: _Entry, measure: bool, floor: int, floor_bg: int):
         """One request through the bank/bus/refresh/mechanism pipeline —
         the host twin of ``simulator._service``."""
         b, row = ent.bank, ent.row
@@ -292,8 +307,11 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
 
         t_act = adj(t_pre + T.tRP) if is_conflict else adj(max(t0, r_act_b))
         needs_act = not is_hit
+        rrd_l_wait = 0
         if needs_act:
             t_act = max(t_act, floor)
+            rrd_l_wait = max(floor_bg - t_act, 0)
+            t_act += rrd_l_wait
 
         gid = b * n_rows + row
         cc_hit = hc.lookup(gid, t_act) and needs_act and hc_gate
@@ -328,8 +346,16 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
         t_rdwr = max(t0, r_rdwr_b) if is_hit else t_act + rcd
         cas = T.tCWL if ent.write else T.tCL
         t_rdwr = max(t_rdwr, data_free[ch] - cas)
-        if not stateful:
-            t_rdwr = clamp_span(t_rdwr, cas + T.tBL, row)
+        settle = (lambda tt: tt) if stateful \
+            else (lambda tt: clamp_span(tt, cas + T.tBL, row))
+        t_free = settle(t_rdwr)
+        if bank_groups:
+            slot = group_slot(b)
+            t_rdwr = max(t_rdwr, last_cas[ch] + T.tCCD_S,
+                         last_cas_bg[slot] + T.tCCD_L)
+            last_cas[ch] = last_cas_bg[slot] = t_rdwr = settle(t_rdwr)
+        else:
+            t_rdwr = t_free
         done = t_rdwr + cas + T.tBL
 
         new_ready_rdwr = t_act + rcd if needs_act else r_rdwr_b
@@ -378,6 +404,8 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
         if do_ref and measure:
             stats["ref_blocked_cycles"] += max(ref_done - max(t0, busy0),
                                                0)
+        stats["ccd_wait_cycles"] += m * (t_rdwr - t_free)
+        stats["rrd_l_wait_cycles"] += m * rrd_l_wait
         return done, t_act, needs_act
 
     serviced = 0
@@ -396,18 +424,23 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
             return (0 if hit else int(HIT_PENALTY)) + ent.seq
         ent = min(window, key=key)
 
-        rank = ent.bank // int(D.n_banks)
-        floor = 0
+        rank = ent.bank // n_banks
+        slot = group_slot(ent.bank)
+        floor = floor_bg = 0
         if frfcfs:
             floor = max(rank_last_act[rank] + T.tRRD,
                         faw_ring[rank][faw_ptr[rank]] + T.tFAW)
+            if bank_groups:
+                floor_bg = max(floor, bg_last_act[slot] + T.tRRD_L)
 
-        done, t_act, needs_act = service(ent, serviced >= warmup, floor)
+        done, t_act, needs_act = service(ent, serviced >= warmup, floor,
+                                         floor_bg)
 
         if needs_act and frfcfs:
             rank_last_act[rank] = max(rank_last_act[rank], t_act)
             faw_ring[rank][faw_ptr[rank]] = t_act
             faw_ptr[rank] = (faw_ptr[rank] + 1) % FAW_DEPTH
+            bg_last_act[slot] = max(bg_last_act[slot], t_act)
 
         cc = ent.core
         pos = ent.idx % mshr

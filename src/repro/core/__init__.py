@@ -7,7 +7,8 @@ The faithful reproduction of the thesis's mechanism (see DESIGN.md §2.1).
 from repro import obs  # noqa: F401
 
 from repro.core.timing import (TimingParams, TimingVec, DDR3_1600,
-                               DDR3_1600_CC_1MS, lowered_for_duration,
+                               DDR3_1600_CC_1MS, DDR4_2400,
+                               lowered_for_duration,
                                ms_to_cycles, ns_to_cycles, CYCLE_NS)
 from repro.core.dram import (DRAMConfig, DDR3_SYSTEM, DRAMEnvelope,
                              GeomParams, INTERLEAVE_KINDS, InterleaveConfig,
@@ -26,6 +27,7 @@ from repro.core import aldram, charge_model, energy, rltl, traces
 __all__ = [
     "ALDRAMConfig", "TEMPERATURE_BINS_C", "aldram",
     "TimingParams", "TimingVec", "DDR3_1600", "DDR3_1600_CC_1MS",
+    "DDR4_2400",
     "lowered_for_duration", "ms_to_cycles", "ns_to_cycles", "CYCLE_NS",
     "DRAMConfig", "DDR3_SYSTEM", "DRAMEnvelope", "GeomParams",
     "INTERLEAVE_KINDS", "InterleaveConfig", "InterleaveParams",

@@ -34,7 +34,7 @@ from typing import NamedTuple
 import numpy as np
 
 from repro.core import charge_model
-from repro.core.timing import TimingParams, ms_to_cycles
+from repro.core.timing import CYCLE_NS, TimingParams, ms_to_cycles
 
 #: DDR3 spec guardband temperature: the margin vanishes here by design.
 TEMP_REFERENCE_C = 85.0
@@ -71,7 +71,8 @@ def module_timings(ald: ALDRAMConfig,
                    timing: TimingParams) -> tuple[int, int]:
     """Module-average safe (tRCD, tRAS) cycles at the config's
     temperature, before per-bank variation; clipped to the spec."""
-    d = charge_model.derive_timings(equivalent_idle_ms(ald.temperature_c))
+    d = charge_model.derive_timings(equivalent_idle_ms(ald.temperature_c),
+                                    timing.tCK_ns)
     return (min(d.tRCD_cycles, timing.tRCD),
             min(d.tRAS_cycles, timing.tRAS))
 
@@ -139,16 +140,17 @@ def thermal_leak_scale(temperature_c: float) -> float:
     return 2.0 ** ((temperature_c - TEMP_REFERENCE_C) / LEAKAGE_DOUBLING_C)
 
 
-def thermal_params_np(th: ThermalConfig, n_segs: int):
+def thermal_params_np(th: ThermalConfig, n_segs: int,
+                      tck_ns: float = CYCLE_NS):
     """Numpy leaves of one point's ``ThermalParams``, padded to the
     grid-wide ``n_segs`` (position-stable: real segments first, padding
     starts at the never-reached cycle ``2**30`` and repeats the last
-    real leak scale)."""
+    real leak scale); segment starts in cycles of ``tck_ns``."""
     S = int(n_segs)
     edge = np.full(S, np.int32(2**30), np.int32)
     leak = np.ones(S, np.float32)
     for i, (ms, tc) in enumerate(th.points):
-        edge[i] = np.int32(ms_to_cycles(ms))
+        edge[i] = np.int32(ms_to_cycles(ms, tck_ns))
         leak[i:] = np.float32(thermal_leak_scale(tc))
     return np.asarray(th.n_segs > 0), edge, leak
 

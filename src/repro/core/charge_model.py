@@ -126,16 +126,18 @@ class DerivedTimings:
     tRAS_cycles: int
 
 
-def derive_timings(duration_ms: float) -> DerivedTimings:
-    """Model-derived lowered timings for a caching duration (Table 6.1)."""
+def derive_timings(duration_ms: float,
+                   tck_ns: float = timing_lib.CYCLE_NS) -> DerivedTimings:
+    """Model-derived lowered timings for a caching duration (Table 6.1),
+    in cycles of ``tck_ns``."""
     rcd = float(t_ready_ns(duration_ms))
     ras = float(t_restore_ns(duration_ms))
     return DerivedTimings(
         duration_ms=duration_ms,
         tRCD_ns=rcd,
         tRAS_ns=ras,
-        tRCD_cycles=timing_lib.ns_to_cycles(rcd),
-        tRAS_cycles=timing_lib.ns_to_cycles(ras),
+        tRCD_cycles=timing_lib.ns_to_cycles(rcd, tck_ns),
+        tRAS_cycles=timing_lib.ns_to_cycles(ras, tck_ns),
     )
 
 
@@ -144,11 +146,14 @@ def derived_table(durations_ms=(1.0, 4.0, 16.0, 64.0)):
     return [derive_timings(d) for d in durations_ms]
 
 
-def lowered_params(duration_ms: float) -> timing_lib.TimingParams:
-    """TimingParams with model-derived tRCD/tRAS for ChargeCache hits."""
-    d = derive_timings(duration_ms)
+def lowered_params(duration_ms: float,
+                   base: timing_lib.TimingParams = timing_lib.DDR3_1600
+                   ) -> timing_lib.TimingParams:
+    """``base`` with model-derived tRCD/tRAS for ChargeCache hits, at
+    ``base``'s clock and never above its own values."""
+    d = derive_timings(duration_ms, base.tCK_ns)
     return dataclasses.replace(
-        timing_lib.DDR3_1600,
-        tRCD=min(d.tRCD_cycles, timing_lib.DDR3_1600.tRCD),
-        tRAS=min(d.tRAS_cycles, timing_lib.DDR3_1600.tRAS),
+        base,
+        tRCD=min(d.tRCD_cycles, base.tRCD),
+        tRAS=min(d.tRAS_cycles, base.tRAS),
     )

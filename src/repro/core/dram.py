@@ -48,6 +48,17 @@ class DRAMConfig:
     n_banks: int = 8          # per rank
     n_rows: int = 65536       # per bank
     row_buffer_bytes: int = 8192
+    #: bank groups per rank (DDR4, DESIGN.md §16): bank ``b`` of a rank
+    #: is in group ``b mod n_bank_groups``, so consecutive bank ids fall
+    #: in different groups (an assumed mapping).  1 = no bank groups,
+    #: the DDR3 model.
+    n_bank_groups: int = 1
+
+    def __post_init__(self):
+        assert self.n_bank_groups >= 1 and \
+            self.n_banks % self.n_bank_groups == 0, (
+                f"n_banks ({self.n_banks}) must split evenly into "
+                f"n_bank_groups ({self.n_bank_groups})")
 
     @property
     def banks_total(self) -> int:
@@ -78,11 +89,16 @@ class DRAMEnvelope:
     max_channels: int = 2
     max_banks_total: int = 16
     max_rows: int = 65536
+    #: the most bank groups of any point: > 1 is the only thing that
+    #: compiles the bank-group path (its registers, rules and counters);
+    #: at 1 the step is the DDR3 step, leaf for leaf (DESIGN.md §16)
+    max_bank_groups: int = 1
 
     def covers(self, cfg: DRAMConfig) -> bool:
         return (self.max_channels >= cfg.n_channels
                 and self.max_banks_total >= cfg.banks_total
-                and self.max_rows >= cfg.n_rows)
+                and self.max_rows >= cfg.n_rows
+                and self.max_bank_groups >= cfg.n_bank_groups)
 
 
 def envelope_of(cfgs: Iterable[DRAMConfig]) -> DRAMEnvelope:
@@ -93,6 +109,7 @@ def envelope_of(cfgs: Iterable[DRAMConfig]) -> DRAMEnvelope:
         max_channels=max(c.n_channels for c in cfgs),
         max_banks_total=max(c.banks_total for c in cfgs),
         max_rows=max(c.n_rows for c in cfgs),
+        max_bank_groups=max(c.n_bank_groups for c in cfgs),
     )
 
 
@@ -111,6 +128,8 @@ class GeomParams(NamedTuple):
     banks_total: jnp.ndarray        # n_channels * n_ranks * n_banks
     banks_per_channel: jnp.ndarray  # n_ranks * n_banks
     row_buffer_bytes: jnp.ndarray
+    n_bank_groups: jnp.ndarray      # per rank (read on the bank-group
+                                    # path only, DESIGN.md §16)
 
 
 def geom_params(cfg: DRAMConfig) -> GeomParams:
@@ -123,6 +142,7 @@ def geom_params(cfg: DRAMConfig) -> GeomParams:
         banks_total=jnp.int32(cfg.banks_total),
         banks_per_channel=jnp.int32(cfg.banks_per_channel),
         row_buffer_bytes=jnp.int32(cfg.row_buffer_bytes),
+        n_bank_groups=jnp.int32(cfg.n_bank_groups),
     )
 
 
@@ -135,6 +155,22 @@ def global_row_id(geom: GeomParams, global_bank, row):
     """Unique id for (bank, row) — the HCRAC tag (thesis Eq. 6.2), over
     the traced geometry."""
     return global_bank * geom.n_rows + row
+
+
+def bank_group_of(geom: GeomParams, global_bank):
+    """Bank group of a global bank within its rank: ``(bank within rank)
+    mod n_bank_groups``, so consecutive bank ids fall in different groups
+    (the assumed DDR4 mapping, DESIGN.md §16)."""
+    return jnp.mod(jnp.mod(global_bank, geom.n_banks), geom.n_bank_groups)
+
+
+def bank_group_slot(geom: GeomParams, global_bank):
+    """Register slot of (rank, bank group) in a ``[max_banks_total]``
+    array: the rank's first global bank id plus the group.  A group index
+    is below ``n_banks``, so slots of different (rank, group) pairs never
+    collide and always lie inside the envelope's bank count."""
+    return (global_bank - jnp.mod(global_bank, geom.n_banks)
+            + bank_group_of(geom, global_bank))
 
 
 def in_active_geometry(geom: GeomParams, bank, row):

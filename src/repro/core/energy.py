@@ -14,7 +14,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.core.timing import TimingParams, DDR3_1600, CYCLE_NS
+from repro.core.timing import TimingParams, DDR3_1600
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,9 +48,17 @@ def energy_nj(stats: dict, timing: TimingParams = DDR3_1600,
     (``bank_act_ras_sum``), the same charge is also reported bank by
     bank as ``act_per_bank`` (summing to ``act``), which is what the
     AL-DRAM benchmark's per-bank spread reads (DESIGN.md §9).
+
+    Cycles convert to seconds at ``timing``'s own clock.  The IDD
+    currents are DDR3's, so a bank-grouped (DDR4) timing set is refused
+    until a DDR4 datasheet table is in the repository.
     """
+    if timing.bank_grouped:
+        raise ValueError(
+            "energy_nj models DDR3 IDD currents only; a bank-grouped "
+            "(DDR4) timing set has no IDD table here yet")
     p = power
-    cyc_s = CYCLE_NS * 1e-9
+    cyc_s = timing.tCK_ns * 1e-9
     if n_channels is not None:
         n_ch, n_rk = int(n_channels), 1
     elif geom is not None:

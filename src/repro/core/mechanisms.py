@@ -248,8 +248,8 @@ def default_nuat_bins(timing: TimingParams = DDR3_1600):
     edges_ms = (8.0, 16.0, 32.0, 48.0, 64.0)
     bins = []
     for e in edges_ms:
-        d = charge_model.derive_timings(e)
-        bins.append((ms_to_cycles(e),
+        d = charge_model.derive_timings(e, timing.tCK_ns)
+        bins.append((ms_to_cycles(e, timing.tCK_ns),
                      min(d.tRCD_cycles, timing.tRCD),
                      min(d.tRAS_cycles, timing.tRAS)))
     return tuple(bins)

@@ -71,6 +71,7 @@ from repro.core.traces import TraceBatch, WorkloadSpec, WORKLOAD_BY_NAME
 from repro.core import mechanisms as registry
 from repro.core import metrics as metrics_lib
 from repro.core.mechanisms import default_nuat_bins  # noqa: F401 (re-export)
+from repro import obs
 from repro.obs import span
 
 # np scalar so Pallas kernel bodies may close over it (see dram.NO_ROW)
@@ -226,6 +227,36 @@ def sim_shape(cfg: SimConfig, n_sets_max: int | None = None,
     )
 
 
+def _check_point(cfg: SimConfig) -> None:
+    """Refuse a grid point whose numbers the engine would misread.  Run
+    on every launched point (``mech_params``), not on the intermediate
+    configs an ``Experiment``'s axes pass through."""
+    # the bank-group path compiles only for a bank-grouped envelope
+    # (DESIGN.md §16): a rule it would silently drop is refused
+    assert not cfg.timing.bank_grouped or cfg.dram.n_bank_groups > 1, (
+        "bank-group timings (tCCD_S/tCCD_L/tRRD_L) need a bank-grouped "
+        "geometry (DRAMConfig.n_bank_groups > 1)")
+    # the lowered set must be in this point's clock: the duration
+    # axis derives it from the point's timing, so a timing axis
+    # applied after it (or a hand-built DDR3 set) is refused
+    uses_lowered = any("lowered" in registry.get(n).consumes
+                       for n in registry.components(cfg.mech.kind))
+    assert not uses_lowered or \
+        cfg.mech.lowered.tCK_ns == cfg.timing.tCK_ns, (
+            "the mechanism's lowered timings are in another clock's "
+            "cycles than the point's timing: derive them with "
+            "lowered_for_duration(ms, timing), or put a timing axis "
+            "before the duration_ms axis")
+    # NUAT's default bins are DDR3-1600 cycles; another clock needs
+    # its own (``default_nuat_bins(timing)``), never DDR3's silently
+    assert not ("nuat" in registry.components(cfg.mech.kind)
+                and cfg.timing.tCK_ns != timing_lib.CYCLE_NS
+                and cfg.mech.nuat_bins == default_nuat_bins()), (
+        "NUAT bins default to DDR3-1600 cycles; pass "
+        "MechanismConfig(nuat_bins=default_nuat_bins(timing)) for a "
+        "timing set with another clock")
+
+
 def mech_params(cfg: SimConfig, hints: dict | None = None,
                 envelope: DRAMEnvelope | None = None) -> MechParams:
     """Flatten ``cfg``'s numeric content into the traced params pytree.
@@ -240,6 +271,7 @@ def mech_params(cfg: SimConfig, hints: dict | None = None,
     block) size to the shared envelope.  All padding is
     behaviour-neutral (bitwise).
     """
+    _check_point(cfg)
     env = envelope if envelope is not None else envelope_of([cfg.dram])
     hints = hints if hints is not None else registry.pad_hints([cfg.mech])
     hints = {n: {**h, "n_banks_padded": env.max_banks_total}
@@ -248,7 +280,7 @@ def mech_params(cfg: SimConfig, hints: dict | None = None,
     # no-drift grid has S == 0 and every drift branch is statically gone
     n_segs = hints.get("aldram", {}).get("n_segs", cfg.mech.thermal.n_segs)
     th_en, th_edge, th_leak = aldram_lib.thermal_params_np(
-        cfg.mech.thermal, n_segs)
+        cfg.mech.thermal, n_segs, cfg.timing.tCK_ns)
     return MechParams(
         timing=timing_lib.traced(cfg.timing),
         geom=geom_params(cfg.dram),
@@ -291,12 +323,23 @@ class SimState(NamedTuple):
     hcrac: hcrac_lib.HCRACState
     # accumulators (int32 scalars; NO large arrays — see perf note in _run)
     stats: dict
+    # bank-group column registers (DESIGN.md §16): the newest RD/WR cycle
+    # per channel and per (rank, bank group) slot (``dram.bank_group_slot``).
+    # None — no carry leaf at all — unless the envelope has bank groups.
+    last_cas: jnp.ndarray | None = None     # [NCH]
+    last_cas_bg: jnp.ndarray | None = None  # [NB]
 
 
 STAT_KEYS = ("n_req", "lat_sum", "acts", "acts_lowered", "hcrac_hits",
              "hcrac_lookups", "row_hits", "row_closed", "row_conflicts",
              "reads", "writes", "pres", "act_ras_sum", "refresh8ms_acts",
              "refs_issued", "ref_blocked_cycles")
+
+#: the bank-group counters (DESIGN.md §16), carried only on the bank-group
+#: path and reported as 0 elsewhere: cycles tCCD_S/tCCD_L pushed a
+#: measured RD/WR past every other rule, and cycles tRRD_L pushed a
+#: measured ACT past tRRD/tFAW (FR-FCFS tier)
+BG_STAT_KEYS = ("ccd_wait_cycles", "rrd_l_wait_cycles")
 
 #: [NB]-shaped stat accumulators (sized to the padded envelope, scattered
 #: at the folded bank index, so entries past the active ``banks_total``
@@ -309,7 +352,7 @@ BANK_STAT_KEYS = ("bank_acts", "bank_act_ras_sum")
 #: scan counters plus the engine-derived ``total_cycles`` (``max`` over
 #: the per-core end times).  Serving launches extend this with their own
 #: counters (``serving.loop.engine.SERVE_REDUCE_KEYS``).
-REDUCE_KEYS = STAT_KEYS + ("total_cycles",)
+REDUCE_KEYS = STAT_KEYS + BG_STAT_KEYS + ("total_cycles",)
 
 
 def _reduce_device(raw_stats: dict, core_end, reduce_keys: tuple):
@@ -322,6 +365,8 @@ def _reduce_device(raw_stats: dict, core_end, reduce_keys: tuple):
     for k in reduce_keys:
         if k == "total_cycles":
             cols.append(jnp.max(core_end, axis=-1))
+        elif k not in raw_stats:  # a bank-group counter off that path
+            cols.append(jnp.zeros(core_end.shape[:-1], jnp.int32))
         else:
             cols.append(raw_stats[k])
     return jnp.stack(cols, axis=-1)
@@ -361,6 +406,12 @@ def _init_state(shape: SimShape, n_cores: int, max_len: int) -> SimState:
     z = lambda *s: jnp.zeros(s, jnp.int32)
     stats = {k: jnp.int32(0) for k in STAT_KEYS}
     stats.update({k: z(nb) for k in BANK_STAT_KEYS})
+    bg = {}
+    if shape.envelope.max_bank_groups > 1:
+        stats.update({k: jnp.int32(0) for k in BG_STAT_KEYS})
+        # deep in the past, so the first column command is unconstrained
+        bg = dict(last_cas=jnp.full((nch,), -INF, jnp.int32),
+                  last_cas_bg=jnp.full((nb,), -INF, jnp.int32))
     return SimState(
         ptr=z(n_cores), last_issue=z(n_cores), last_complete=z(n_cores),
         mshr_ring=z(n_cores, shape.mshr), ring_idx=z(n_cores),
@@ -372,6 +423,7 @@ def _init_state(shape: SimShape, n_cores: int, max_len: int) -> SimState:
         cmd_bus_free=z(nch), data_bus_free=z(nch),
         hcrac=hcrac_lib.init(shape.hcrac),
         stats=stats,
+        **bg,
     )
 
 
@@ -380,7 +432,8 @@ def _acc(stats, key, val):
 
 
 def _service(shape: SimShape, p: MechParams, st: SimState, t_arr, bank, row,
-             is_write, next_same, measure, enable, act_floor=None):
+             is_write, next_same, measure, enable, act_floor=None,
+             act_floor_bg=None):
     """Serve one request; returns (new bank/bus/hcrac state pieces, done).
 
     ``enable`` marks a live scan step: padded no-op steps (see ``_run``)
@@ -393,13 +446,21 @@ def _service(shape: SimShape, p: MechParams, st: SimState, t_arr, bank, row,
     the return grows a fourth element ``(t_act, needs_act)`` so the
     caller can update its rank ACT registers.  ``None`` (every in-order
     caller) leaves the traced computation statically identical to the
-    pre-controller engine.
+    pre-controller engine.  ``act_floor_bg`` (bank-group path only) is
+    the tighter floor with the same-group tRRD_L; the cycles it adds over
+    ``act_floor`` count as ``rrd_l_wait_cycles``.
+
+    The bank-group path (DESIGN.md §16) is compiled only when the
+    envelope has bank groups (``max_bank_groups > 1``): a RD/WR then
+    issues no earlier than tCCD_S after the channel's newest column
+    command and tCCD_L after its (rank, bank group)'s.
     """
     T = p.timing
     geom = p.geom
     hshape = shape.hcrac
     ch = dram_lib.channel_of(geom, bank)
     stats = dict(st.stats)
+    bg_path = shape.envelope.max_bank_groups > 1
 
     t0 = jnp.maximum(t_arr, st.cmd_bus_free[ch])
 
@@ -464,6 +525,11 @@ def _service(shape: SimShape, p: MechParams, st: SimState, t_arr, bank, row,
         # (a row hit issues no ACT; its t_act is only a mechanism-clock
         # read and must stay untouched)
         t_act = jnp.where(needs_act, jnp.maximum(t_act, act_floor), t_act)
+    if act_floor_bg is not None:
+        t_act_s = t_act
+        t_act = jnp.where(needs_act, jnp.maximum(t_act, act_floor_bg), t_act)
+        _acc(stats, "rrd_l_wait_cycles",
+             measure.astype(jnp.int32) * (t_act - t_act_s))
 
     gid = dram_lib.global_row_id(geom, bank, row)
     cc_hit, hc = hcrac_lib.lookup(hshape, hc, gid, t_act, enable=enable,
@@ -528,9 +594,21 @@ def _service(shape: SimShape, p: MechParams, st: SimState, t_arr, bank, row,
     # legacy tier: the RD/WR command *and* its burst must clear the
     # blackout window too, like PRE/ACT above (satellite 1 — the burst
     # used to be issued straight through the tRFC blackout)
-    t_rdwr = jnp.where(
-        legacy, dram_lib.refresh_clamp_span(T, t_rdwr, cas + T.tBL, row),
-        t_rdwr)
+    clamp_rw = lambda tt: jnp.where(
+        legacy, dram_lib.refresh_clamp_span(T, tt, cas + T.tBL, row), tt)
+    if bg_path:
+        # column-to-column spacing: tCCD_S on the channel, tCCD_L within
+        # the (rank, bank group); the wait is what it adds past every
+        # other rule (the legacy clamp is monotone, so it is >= 0)
+        g_slot = dram_lib.bank_group_slot(geom, bank)
+        t_free = clamp_rw(t_rdwr)
+        t_rdwr = jnp.maximum(t_rdwr, jnp.maximum(
+            st.last_cas[ch] + T.tCCD_S, st.last_cas_bg[g_slot] + T.tCCD_L))
+        t_rdwr = clamp_rw(t_rdwr)
+        _acc(stats, "ccd_wait_cycles",
+             measure.astype(jnp.int32) * (t_rdwr - t_free))
+    else:
+        t_rdwr = clamp_rw(t_rdwr)
     done = t_rdwr + cas + T.tBL
 
     # --- bank state updates -------------------------------------------------
@@ -581,7 +659,7 @@ def _service(shape: SimShape, p: MechParams, st: SimState, t_arr, bank, row,
     _acc(stats, "pres", m * (is_conflict.astype(jnp.int32)
                              + auto_pre.astype(jnp.int32)))
     _acc(stats, "act_ras_sum", m * needs_act * ras)
-    ref8 = needs_act & measure & (tsr < ms_to_cycles(8.0))
+    ref8 = needs_act & measure & (tsr < T.cycles_8ms)
     _acc(stats, "refresh8ms_acts", ref8)
     # stateful-tier refresh stats: REFs observed at command arrivals, and
     # the blackout cycles a REF imposed beyond the bank's prior business
@@ -644,6 +722,11 @@ def _service(shape: SimShape, p: MechParams, st: SimState, t_arr, bank, row,
         hcrac=hc,
         stats=stats,
     )
+    if bg_path:
+        new_st = new_st._replace(
+            last_cas=st.last_cas.at[ch].set(w(t_rdwr, st.last_cas[ch])),
+            last_cas_bg=st.last_cas_bg.at[g_slot].set(
+                w(t_rdwr, st.last_cas_bg[g_slot])))
     if act_floor is not None:
         return new_st, done, events, (t_act, needs_act)
     return new_st, done, events
@@ -908,7 +991,29 @@ def _run_grid(shape: SimShape, params: MechParams, traces: dict,
     return out
 
 
-def _rltl_post_pass(events: Events):
+def _rltl_edges_cycles(tck_ns: float = timing_lib.CYCLE_NS) -> np.ndarray:
+    """``RLTL_EDGES_MS`` in cycles of ``tck_ns``."""
+    return np.array([ms_to_cycles(e, tck_ns) for e in RLTL_EDGES_MS],
+                    np.int32)
+
+
+def _rltl_edges(grid: Sequence[SimConfig], events):
+    """Per-lane RLTL bucket edges ``[G, B]`` in each point's own clock
+    for a launch's ``events`` (whose grid axis a sharded launch pads past
+    ``len(grid)`` by repeating its last point), or None where every point
+    runs the default clock — the constant edges the passes fall back to,
+    so a DDR3 launch compiles as it always has — or nothing was
+    collected."""
+    if events is None or all(cfg.timing.tCK_ns == timing_lib.CYCLE_NS
+                             for cfg in grid):
+        return None
+    lanes = events.act_gid.shape[-2]
+    return np.stack([_rltl_edges_cycles(cfg.timing.tCK_ns) for cfg in grid]
+                    + [_rltl_edges_cycles(grid[-1].timing.tCK_ns)]
+                    * (lanes - len(grid)))
+
+
+def _rltl_post_pass(events: Events, edges=None):
     """Match each measured ACT to the most recent PRE of the same row.
 
     Exact reconstruction of the per-row "last PRE" history: all PRE and ACT
@@ -916,8 +1021,9 @@ def _rltl_post_pass(events: Events):
     alternate ACT ... PRE, ACT ... PRE (a row must be precharged between
     activations), so an ACT's predecessor in the sorted order is its row's
     latest preceding PRE (or another event meaning "cold/open history").
-    Returns the RLTL interval histogram (thesis Fig 3.2 buckets) and the
-    number of ACTs with a valid preceding PRE.
+    Returns the RLTL interval histogram (thesis Fig 3.2 buckets, upper
+    ``edges`` in cycles; the default clock's when None) and the number
+    of ACTs with a valid preceding PRE.
     """
     act_gid = np.asarray(events.act_gid)
     act_t = np.asarray(events.act_t)
@@ -942,13 +1048,13 @@ def _rltl_post_pass(events: Events):
     prev_is_pre[1:] = kind[:-1] == 0
     valid = is_act & prev_same & prev_is_pre
     intervals = np.where(valid, t - np.roll(t, 1), 0)[valid]
-    edges = np.array([ms_to_cycles(e) for e in RLTL_EDGES_MS])
+    edges = _rltl_edges_cycles() if edges is None else np.asarray(edges)
     bucket = np.searchsorted(edges, intervals, side="left")
     hist = np.bincount(bucket, minlength=len(RLTL_EDGES_MS) + 1).astype(np.int64)
     return hist, int(valid.sum())
 
 
-def _rltl_device(events: Events):
+def _rltl_device(events: Events, edges=None):
     """On-device mirror of ``_rltl_post_pass``: a sorted-segment (per
     row id) reduction over the event stream, pure JAX — bitwise the host
     pass (tests/test_simulator.py).
@@ -979,7 +1085,7 @@ def _rltl_device(events: Events):
     valid = (kind == 1) & prev_same & prev_is_pre & (gid != sent)
     prev_t = jnp.concatenate([t[:1], t[:-1]])
     intervals = jnp.where(valid, t - prev_t, 0)
-    edges = jnp.asarray([ms_to_cycles(e) for e in RLTL_EDGES_MS],
+    edges = jnp.asarray(_rltl_edges_cycles() if edges is None else edges,
                         jnp.int32)
     bucket = jnp.searchsorted(edges, intervals, side="left").astype(
         jnp.int32)
@@ -989,18 +1095,23 @@ def _rltl_device(events: Events):
 
 
 @jax.jit
-def _rltl_hist_device(events: Events):
+def _rltl_hist_device(events: Events, edges=None):
     """``_rltl_device`` vmapped over however many leading batch axes the
-    engine emitted ([grid] for sweeps, [batch, grid] for sweep_traces)."""
+    engine emitted ([grid] for sweeps, [batch, grid] for sweep_traces);
+    ``edges`` is None (the default clock's) or carries the same leading
+    axes."""
     fn = _rltl_device
     for _ in range(events.act_gid.ndim - 1):
         fn = jax.vmap(fn)
-    return fn(events)
+    return fn(events, edges)
 
 
-def _rltl_np(events: Events | None, on_device: bool | None = None):
+def _rltl_np(events: Events | None, edges=None,
+             on_device: bool | None = None):
     """The RLTL post-pass, dispatched per backend; returns host views
-    ``(hist [..., B+1] int64, total [...] int64)``.
+    ``(hist [..., B+1] int64, total [...] int64)``.  ``edges`` are the
+    per-point bucket edges of ``_rltl_edges`` (``[G, B]``, broadcast over
+    a leading batch axis), or None for the default clock's.
 
     On accelerators the segmented pass runs on device
     (``_rltl_hist_device``) and only the histograms cross to the host —
@@ -1015,18 +1126,22 @@ def _rltl_np(events: Events | None, on_device: bool | None = None):
         return None, None
     if on_device is None:
         on_device = jax.default_backend() != "cpu"
+    lead = events.act_gid.shape[:-1]
+    if edges is not None:
+        edges = np.broadcast_to(np.asarray(edges, np.int32),
+                                lead + (len(RLTL_EDGES_MS),))
     with span("rltl"):
         if on_device:
-            hist, total = _rltl_hist_device(events)
+            hist, total = _rltl_hist_device(events, edges)
             return np.asarray(hist).astype(np.int64), \
                 np.asarray(total).astype(np.int64)
         ev = Events(*(np.asarray(e) for e in events))
-        lead = ev.act_gid.shape[:-1]
         hist = np.zeros(lead + (len(RLTL_EDGES_MS) + 1,), np.int64)
         total = np.zeros(lead, np.int64)
         for idx in np.ndindex(*lead):
             hist[idx], total[idx] = _rltl_post_pass(
-                Events(*(x[idx] for x in ev)))
+                Events(*(x[idx] for x in ev)),
+                None if edges is None else edges[idx])
         return hist, total
 
 
@@ -1054,6 +1169,8 @@ def _finalize(raw_stats: dict, core_end, rltl: tuple,
     on-device post-pass (``_rltl_np``), or ``(None, None)`` when the run
     was collected without events."""
     stats = {k: np.asarray(v) for k, v in raw_stats.items()}
+    for k in BG_STAT_KEYS:  # carried only on the bank-group path
+        stats.setdefault(k, np.int32(0))
     hist, rltl_total = rltl
     stats["rltl_hist"] = None if hist is None else np.asarray(hist)
     stats["rltl_total"] = None if rltl_total is None else int(rltl_total)
@@ -1100,17 +1217,20 @@ def simulate(batch: TraceBatch, cfg: SimConfig = SimConfig()) -> dict:
         f"trace arrival clock ({arrival} cycles) overflows the int32 "
         f"horizon ({int(INF)}); split the stream into shorter chunks")
     warmup = jnp.int32(int(cfg.warmup_frac * n_steps))
+    shape = sim_shape(cfg)
     if cfg.controller == "frfcfs":
         from repro.controller import engine as ctrl_engine
+        _record_launch("_run_window", shape, n_steps)
         raw_stats, core_end, events = ctrl_engine._run_window(
-            sim_shape(cfg), cfg.window, mech_params(cfg), trace, warmup,
-            n_steps)
+            shape, cfg.window, mech_params(cfg), trace, warmup, n_steps)
     else:
-        raw_stats, core_end, events = _run(sim_shape(cfg),
-                                           mech_params(cfg), trace,
+        _record_launch("_run", shape, n_steps)
+        raw_stats, core_end, events = _run(shape, mech_params(cfg), trace,
                                            warmup, n_steps)
-    return _finalize(raw_stats, core_end, _rltl_np(events), batch.length,
-                     cfg)
+    edges = None if cfg.timing.tCK_ns == timing_lib.CYCLE_NS \
+        else _rltl_edges_cycles(cfg.timing.tCK_ns)
+    return _finalize(raw_stats, core_end, _rltl_np(events, edges),
+                     batch.length, cfg)
 
 
 def _shard_grid(stacked: MechParams, n_grid: int):
@@ -1135,6 +1255,11 @@ def _shard_grid(stacked: MechParams, n_grid: int):
     stacked = jax.tree_util.tree_map(
         lambda x: jax.device_put(x, sharding), stacked)
     return stacked, n_grid + pad
+
+
+def _record_launch(engine: str, shape: SimShape, n_steps: int) -> None:
+    """Count a dispatched launch's scan steps (``repro.obs.scan_steps``)."""
+    obs.record_launch(engine, shape.envelope.max_bank_groups > 1, n_steps)
 
 
 def _uniform_backend(grid: Sequence[SimConfig]) -> str:
@@ -1284,11 +1409,13 @@ def _launch_batch(shape, stacked, trace, warmup, n_steps: int,
             "the frfcfs controller tier runs the ref engine only")
         from repro.controller import engine as ctrl_engine
         (stacked, ns_idx), _ = _shard_grid((stacked, ns_idx), n_grid)
+        _record_launch("_run_window_batched", shape, n_steps)
         return ctrl_engine._run_window_batched(
             shape, window, stacked, trace, warmup, n_steps,
             collect_events, ns_geoms, ns_idx, reduce_keys)
     if backend == "pallas":
         from repro.kernels.sim_step import ops as sim_step_ops
+        _record_launch("sim_step.run_sweep", shape, n_steps)
         out = sim_step_ops.run_sweep(shape, stacked, trace, warmup,
                                      n_steps, collect_events, ns_geoms,
                                      ns_idx)
@@ -1296,6 +1423,7 @@ def _launch_batch(shape, stacked, trace, warmup, n_steps: int,
             return _reduce_jit(out[0], out[1], reduce_keys)
         return out
     (stacked, ns_idx), _ = _shard_grid((stacked, ns_idx), n_grid)
+    _record_launch("_run_batched", shape, n_steps)
     return _run_batched(shape, stacked, trace, warmup, n_steps,
                         collect_events, ns_geoms, ns_idx, reduce_keys)
 
@@ -1312,7 +1440,7 @@ def _drain_batch(out, grid, lengths, n_grid: int,
     with span("d2h"):
         stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
         core_np = np.asarray(core_end)
-    hist_np, total_np = _rltl_np(events)
+    hist_np, total_np = _rltl_np(events, _rltl_edges(grid, events))
     with span("finalize"):
         return [
             _finalize({k: v[g] for k, v in stats_np.items()}, core_np[g],
@@ -1389,9 +1517,11 @@ def _launch_grid(shape, stacked, traces, warmups, n_steps: int,
     (traces, warmups), _ = _shard_grid((traces, warmups), n_batch)
     if controller == "frfcfs":
         from repro.controller import engine as ctrl_engine
+        _record_launch("_run_window_grid", shape, n_steps)
         return ctrl_engine._run_window_grid(
             shape, window, stacked, traces, warmups, n_steps,
             collect_events, ns_geoms, ns_idx, reduce_keys)
+    _record_launch("_run_grid", shape, n_steps)
     return _run_grid(shape, stacked, traces, warmups, n_steps,
                      collect_events, ns_geoms, ns_idx, reduce_keys)
 
@@ -1406,7 +1536,7 @@ def _drain_grid(out, grid, batches, n_batch: int,
         stats_np = {k: np.asarray(v)
                     for k, v in raw_stats.items()}  # [B, G]
         core_np = np.asarray(core_end)
-    hist_np, total_np = _rltl_np(events)
+    hist_np, total_np = _rltl_np(events, _rltl_edges(grid, events))
     rows = []
     for b in range(n_batch):
         with span("finalize"):
@@ -1631,11 +1761,13 @@ def _launch_synth(shape, n_cores: int, max_len: int, stacked, wstack,
         from repro.controller import engine as ctrl_engine
         (stacked, wstack, ilstack, warmups), _ = _shard_grid(
             (stacked, wstack, ilstack, warmups), n_grid)
+        _record_launch("_run_window_synth_batched", shape, n_steps)
         return ctrl_engine._run_window_synth_batched(
             shape, window, n_cores, max_len, stacked, wstack, ilstack,
             warmups, n_steps, collect_events, reduce_keys)
     if backend == "pallas":
         from repro.kernels.sim_step import ops as sim_step_ops
+        _record_launch("sim_step.run_synth", shape, n_steps)
         out = sim_step_ops.run_synth(
             shape, n_cores, max_len, stacked, wstack, ilstack, warmups,
             n_steps, collect_events)
@@ -1644,6 +1776,7 @@ def _launch_synth(shape, n_cores: int, max_len: int, stacked, wstack,
         return out
     (stacked, wstack, ilstack, warmups), _ = _shard_grid(
         (stacked, wstack, ilstack, warmups), n_grid)
+    _record_launch("_run_synth_batched", shape, n_steps)
     return _run_synth_batched(shape, n_cores, max_len, stacked, wstack,
                               ilstack, warmups, n_steps, collect_events,
                               reduce_keys)
@@ -1658,7 +1791,7 @@ def _drain_synth(out, grid, n_grid: int,
     with span("d2h"):
         stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
         core_np = np.asarray(core_end)
-    hist_np, total_np = _rltl_np(events)
+    hist_np, total_np = _rltl_np(events, _rltl_edges(grid, events))
     with span("finalize"):
         return [
             _finalize({k: v[g] for k, v in stats_np.items()}, core_np[g],

@@ -37,12 +37,17 @@ AXIS_BUILDERS: dict[str, Callable[[SimConfig, Any], SimConfig]] = {}
 #: channel-sensitivity variants plus bank-count studies.  All pad into
 #: one ``DRAMEnvelope`` inside a sweep, so a geometry axis rides the
 #: same single compilation as every other axis (DESIGN.md §8).
+#: ``ddr4_2ch`` is DDR4 x8 8 Gb: 16 banks in 4 bank groups per rank
+#: (JESD79-4), for the ``DDR4_2400`` timing set (DESIGN.md §16).
 GEOMETRY_PRESETS: dict[str, DRAMConfig] = {
     "ddr3_1ch": DRAMConfig(n_channels=1),
     "ddr3_2ch": DRAMConfig(n_channels=2),
     "ddr3_1ch_4bank": DRAMConfig(n_channels=1, n_banks=4),
     "ddr3_1ch_16bank": DRAMConfig(n_channels=1, n_banks=16),
     "ddr3_2ch_16bank": DRAMConfig(n_channels=2, n_banks=16),
+    "ddr4_2ch": DRAMConfig(n_channels=2, n_ranks=1, n_banks=16,
+                           n_bank_groups=4, n_rows=65536,
+                           row_buffer_bytes=8192),
 }
 
 
@@ -70,11 +75,12 @@ def _axis_capacity(cfg: SimConfig, n_entries: int) -> SimConfig:
 @register_axis("duration_ms")
 def _axis_duration(cfg: SimConfig, ms: float) -> SimConfig:
     """Caching duration: sets the HCRAC expiry *and* the lowered timing
-    set the charge model derives for that duration (Table 6.1)."""
-    hcrac = dataclasses.replace(cfg.mech.hcrac,
-                                caching_cycles=ms_to_cycles(ms))
+    set for that duration (Table 6.1), both at the point's own clock and
+    the lowered set on its own base timing (DESIGN.md §16)."""
+    hcrac = dataclasses.replace(
+        cfg.mech.hcrac, caching_cycles=ms_to_cycles(ms, cfg.timing.tCK_ns))
     mech = dataclasses.replace(cfg.mech, hcrac=hcrac,
-                               lowered=lowered_for_duration(ms))
+                               lowered=lowered_for_duration(ms, cfg.timing))
     return dataclasses.replace(cfg, mech=mech)
 
 

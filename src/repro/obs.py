@@ -14,6 +14,11 @@ persistent compilation cache (timed apart as ``cache_load``).  The
 listener that feeds it is registered once, when this module is first
 imported (``repro.core`` imports it before anything can compile).
 
+``scan_steps()`` returns the scan steps the engine launches ran, by
+engine jit and by whether the bank-group path was compiled in: the
+launch functions record each launch's scan length on the host when
+they dispatch it, never per step.
+
 This module is the only one in the program that touches
 ``jax.profiler`` or ``jax.monitoring``.
 """
@@ -32,8 +37,12 @@ PHASES = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
 }
 
+#: the two values of ``scan_steps()``'s path key
+BANK_GROUPS, NO_BANK_GROUPS = "bank_groups", "no_bank_groups"
+
 _lock = threading.Lock()
 _counts: dict[tuple[str, str], list] = {}
+_steps: dict[tuple[str, str], int] = {}
 
 
 def span(name: str, **args):
@@ -64,6 +73,23 @@ def jit_cache() -> dict[str, dict[str, tuple[int, float]]]:
         for (phase, fun), (n, s) in _counts.items():
             out.setdefault(phase, {})[fun] = (n, s)
     return out
+
+
+def record_launch(engine: str, bank_groups: bool, steps: int) -> None:
+    """Count one dispatched launch of ``engine`` (its jit's name) that
+    scans ``steps`` steps, on the bank-group path or not."""
+    key = (engine, BANK_GROUPS if bank_groups else NO_BANK_GROUPS)
+    with _lock:
+        _steps[key] = _steps.get(key, 0) + int(steps)
+
+
+def scan_steps() -> dict[tuple[str, str], int]:
+    """``{(engine, path): steps}`` since the process started: the scan
+    steps every launch of each engine jit ran, with ``path`` either
+    ``"bank_groups"`` (the bank-group path compiled in, DESIGN.md §16)
+    or ``"no_bank_groups"``."""
+    with _lock:
+        return dict(_steps)
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)

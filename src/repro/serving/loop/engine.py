@@ -366,8 +366,10 @@ def _serve_reduce(shape: ServingShape, sim_stats, serve_stats, now,
             cols.append(jnp.full_like(now, shape.n_steps))
         elif k in serve_stats:
             cols.append(serve_stats[k])
-        else:
+        elif k in sim_stats:
             cols.append(sim_stats[k])
+        else:  # a bank-group counter off that path (DESIGN.md §16)
+            cols.append(jnp.zeros_like(now))
     return jnp.stack(cols, axis=-1)
 
 

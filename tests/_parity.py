@@ -15,7 +15,8 @@ import numpy as np
 BITWISE_KEYS = ("n_req", "lat_sum", "acts", "acts_lowered", "hcrac_hits",
                 "hcrac_lookups", "row_hits", "row_closed", "row_conflicts",
                 "reads", "writes", "pres", "act_ras_sum", "refresh8ms_acts",
-                "refs_issued", "ref_blocked_cycles", "total_cycles")
+                "refs_issued", "ref_blocked_cycles", "ccd_wait_cycles",
+                "rrd_l_wait_cycles", "total_cycles")
 
 
 def assert_cell_matches(ref: dict, got: dict, rltl: bool = False):
