@@ -1,13 +1,20 @@
 """The traced FR-FCFS window engine (DESIGN.md §15).
 
-One ``lax.scan`` step = admit-then-serve: a bounded ``fori_loop`` admits
-up to the traced window cap from the per-core issue fronts (per-core
-program order, MSHR- and dependency-gated, exactly the in-order engine's
-issue formula), then one masked argmin over the window picks the request
-to serve — row hits first, oldest (admission sequence) first — and the
+One ``lax.scan`` step = admit-then-serve: a ``while_loop`` admits from
+the per-core issue fronts (per-core program order, MSHR- and
+dependency-gated, exactly the in-order engine's issue formula) until an
+attempt fails or ``W`` attempts have run (at most the traced window cap
+stick; a failed attempt writes nothing, so every later one would fail
+alike).  Then one masked argmin over the window picks the request to
+serve — row hits first, oldest (admission sequence) first — and the
 shared ``simulator._service`` executes it with a per-rank tRRD/tFAW ACT
 floor.  The carry is the in-order ``SimState`` plus ``O(W + ranks)``
 window/rank registers: small, masked writes only (the §2.1 perf rule).
+The lanes of a vmapped launch run the admission loop together while
+any of them still admits (a ``psum`` over the vmap axes they span); a
+lane that is done re-runs its failed attempt, which writes nothing, so
+no lane's result has to be masked.  The loop carries only what an
+attempt writes (``Admission``).
 
 Tier contract (tests/test_controller.py, tests/test_oracle.py):
 
@@ -78,6 +85,9 @@ class WindowState(NamedTuple):
     # controller clock + admission counter (scalars)
     now: jnp.ndarray   # decision horizon: requests issued <= now admit
     seq: jnp.ndarray
+    # admission attempts this lane has run, the failing one included
+    # (``repro.obs.admit_attempts``; no stat reads it)
+    attempts: jnp.ndarray
     # newest ACT cycle (running max) per (rank, bank group) slot
     # (``dram.bank_group_slot``), for tRRD_L: present only on the
     # bank-group path (DESIGN.md §16), else no carry leaf at all
@@ -101,14 +111,47 @@ def _init_window(shape: SimShape, n_cores: int, max_len: int,
         rank_last_act=jnp.full((nr,), NEG, jnp.int32),
         faw_ring=jnp.full((nr, FAW_DEPTH), NEG, jnp.int32),
         faw_ptr=jnp.zeros((nr,), jnp.int32),
-        now=jnp.int32(0), seq=jnp.int32(0),
+        now=jnp.int32(0), seq=jnp.int32(0), attempts=jnp.int32(0),
         bg_last_act=(jnp.full((nr,), NEG, jnp.int32)
                      if shape.envelope.max_bank_groups > 1 else None),
     )
 
 
+class Admission(NamedTuple):
+    """The admission loop's carry: what an attempt writes — the cores'
+    issue fronts (``SimState.ptr`` / ``last_issue``) and the
+    ``WindowState`` registers of the same names."""
+    ptr: jnp.ndarray
+    last_issue: jnp.ndarray
+    w_valid: jnp.ndarray
+    w_core: jnp.ndarray
+    w_idx: jnp.ndarray
+    w_bank: jnp.ndarray
+    w_row: jnp.ndarray
+    w_write: jnp.ndarray
+    w_ns: jnp.ndarray
+    w_arr: jnp.ndarray
+    w_seq: jnp.ndarray
+    yg_served: jnp.ndarray
+    ring_served: jnp.ndarray
+    now: jnp.ndarray
+    seq: jnp.ndarray
+
+    @classmethod
+    def of(cls, ws: WindowState) -> "Admission":
+        st = ws.sim
+        return cls(st.ptr, st.last_issue,
+                   *(getattr(ws, f) for f in cls._fields[2:]))
+
+    def into(self, ws: WindowState) -> WindowState:
+        sim = ws.sim._replace(ptr=self.ptr, last_issue=self.last_issue)
+        return ws._replace(sim=sim, **{f: getattr(self, f)
+                                       for f in self._fields[2:]})
+
+
 def _make_window_step(shape: SimShape, W: int, p: MechParams, trace: dict,
-                      warmup_steps, collect_events: bool = True):
+                      warmup_steps, collect_events: bool = True,
+                      lane_axes: tuple = ()):
     gap = trace["gap"]
     bank = trace["bank"]
     row = trace["row"]
@@ -122,70 +165,86 @@ def _make_window_step(shape: SimShape, W: int, p: MechParams, trace: dict,
     cores = jnp.arange(n_cores)
     bg_path = shape.envelope.max_bank_groups > 1
 
-    def admit_one(_, ws: WindowState) -> WindowState:
+    def admit_one(a: Admission, mshr_ring, yg_done):
         """Try to admit one request: the earliest-issue eligible core's
         front request, if the window has capacity and the request has
         arrived (``issue <= now``; an empty window instead fast-forwards
-        ``now`` — the controller idles until the next arrival)."""
-        st = ws.sim
-        ptr_c = jnp.clip(st.ptr, 0, L - 1)
-        take = lambda a: jnp.take_along_axis(a, ptr_c[:, None],
-                                             axis=1)[:, 0]
+        ``now`` — the controller idles until the next arrival).  Returns
+        the new carry and whether the request was admitted; a failed
+        attempt returns the carry unchanged."""
+        ptr_c = jnp.clip(a.ptr, 0, L - 1)
+        take = lambda arr: jnp.take_along_axis(arr, ptr_c[:, None],
+                                               axis=1)[:, 0]
         g = take(gap)
         d = take(dep)
         # program-order MSHR slot: request i occupies slot i % mshr (the
         # in-order engine's ring_idx is ptr % mshr by construction, so
         # the gathered completion bound is the identical value)
-        pos = jnp.mod(st.ptr, mshr)
-        issue = jnp.maximum(st.last_issue + g, st.mshr_ring[cores, pos])
-        issue = jnp.maximum(issue, jnp.where(d, ws.yg_done, 0))
+        pos = jnp.mod(a.ptr, mshr)
+        issue = jnp.maximum(a.last_issue + g, mshr_ring[cores, pos])
+        issue = jnp.maximum(issue, jnp.where(d, yg_done, 0))
         # a core is eligible when it has requests left, its MSHR slot's
         # occupant (request i - mshr) has been serviced (completion time
         # known), and a dependency's producer (the core's youngest
         # admitted request) has been serviced
-        elig = ((st.ptr < length) & ws.ring_served[cores, pos]
-                & (~d | ws.yg_served))
+        elig = ((a.ptr < length) & a.ring_served[cores, pos]
+                & (~d | a.yg_served))
         issue = jnp.where(elig, issue, INF)
         c = jnp.argmin(issue).astype(jnp.int32)
         t_iss = issue[c]
 
-        occ = jnp.sum(ws.w_valid.astype(jnp.int32))
+        occ = jnp.sum(a.w_valid.astype(jnp.int32))
         can = ((occ < p.win_cap) & (t_iss < INF)
-               & ((t_iss <= ws.now) | (occ == 0)))
-        slot = jnp.argmin(ws.w_valid).astype(jnp.int32)  # first free
+               & ((t_iss <= a.now) | (occ == 0)))
+        slot = jnp.argmin(a.w_valid).astype(jnp.int32)  # first free
         b_f, r_f = fold_address(p.geom, bank[c, ptr_c[c]],
                                 row[c, ptr_c[c]])
         wr = lambda arr, val: arr.at[slot].set(
             jnp.where(can, val, arr[slot]))
-        sim2 = st._replace(
-            ptr=st.ptr.at[c].add(can.astype(jnp.int32)),
-            last_issue=st.last_issue.at[c].set(
-                jnp.where(can, t_iss, st.last_issue[c])),
-        )
-        return ws._replace(
-            sim=sim2,
-            w_valid=wr(ws.w_valid, True),
-            w_core=wr(ws.w_core, c),
-            w_idx=wr(ws.w_idx, st.ptr[c]),
-            w_bank=wr(ws.w_bank, b_f),
-            w_row=wr(ws.w_row, r_f),
-            w_write=wr(ws.w_write, is_write[c, ptr_c[c]]),
-            w_ns=wr(ws.w_ns, next_same[c, ptr_c[c]]),
-            w_arr=wr(ws.w_arr, t_iss),
-            w_seq=wr(ws.w_seq, ws.seq),
-            yg_served=ws.yg_served.at[c].set(
-                jnp.where(can, False, ws.yg_served[c])),
-            ring_served=ws.ring_served.at[c, pos[c]].set(
-                jnp.where(can, False, ws.ring_served[c, pos[c]])),
+        return Admission(
+            ptr=a.ptr.at[c].add(can.astype(jnp.int32)),
+            last_issue=a.last_issue.at[c].set(
+                jnp.where(can, t_iss, a.last_issue[c])),
+            w_valid=wr(a.w_valid, True),
+            w_core=wr(a.w_core, c),
+            w_idx=wr(a.w_idx, a.ptr[c]),
+            w_bank=wr(a.w_bank, b_f),
+            w_row=wr(a.w_row, r_f),
+            w_write=wr(a.w_write, is_write[c, ptr_c[c]]),
+            w_ns=wr(a.w_ns, next_same[c, ptr_c[c]]),
+            w_arr=wr(a.w_arr, t_iss),
+            w_seq=wr(a.w_seq, a.seq),
+            yg_served=a.yg_served.at[c].set(
+                jnp.where(can, False, a.yg_served[c])),
+            ring_served=a.ring_served.at[c, pos[c]].set(
+                jnp.where(can, False, a.ring_served[c, pos[c]])),
             now=jnp.where(can & (occ == 0),
-                          jnp.maximum(ws.now, t_iss), ws.now),
-            seq=ws.seq + can.astype(jnp.int32),
-        )
+                          jnp.maximum(a.now, t_iss), a.now),
+            seq=a.seq + can.astype(jnp.int32),
+        ), can
 
     def step(ws: WindowState, step_idx):
-        # 1. admission: up to W attempts refill the window (at most
-        # win_cap can stick; extra iterations are masked no-ops)
-        ws = jax.lax.fori_loop(0, W, admit_one, ws)
+        # 1. admission: attempts refill the window until one fails or W
+        # have run (at most win_cap stick).  A failed attempt changes
+        # nothing, so every later one in this step would fail alike.  The
+        # lanes of a vmapped launch loop together while any lane is live
+        # (``lane_axes``: the vmap axes they span); a lane whose loop has
+        # ended re-runs its failed attempt, a no-op it does not count.
+        def attempt(c):
+            i, live, n_try, a = c
+            a, can = admit_one(a, ws.sim.mshr_ring, ws.yg_done)
+            return i + 1, live & can, n_try + live.astype(jnp.int32), a
+
+        def again(c):
+            i, live, _, _ = c
+            if lane_axes:
+                live = jax.lax.psum(live.astype(jnp.int32), lane_axes) > 0
+            return (i < W) & live
+
+        _, _, n_try, adm = jax.lax.while_loop(
+            again, attempt,
+            (jnp.int32(0), jnp.bool_(True), jnp.int32(0), Admission.of(ws)))
+        ws = adm.into(ws)._replace(attempts=ws.attempts + n_try)
         st = ws.sim
 
         # 2. FR-FCFS selection: masked argmin over (hit-first, oldest
@@ -282,11 +341,13 @@ def _make_window_step(shape: SimShape, W: int, p: MechParams, trace: dict,
 
 def _run_window_impl(shape: SimShape, W: int, params: MechParams,
                      trace: dict, warmup_steps, n_steps: int,
-                     collect_events: bool = True):
+                     collect_events: bool = True, lane_axes: tuple = ()):
     """Window-engine sibling of ``simulator._run_impl``: same trace
     contract (``next_same`` recomputed over the folded stream when
-    absent), same ``(stats, core_end, events)`` return, same
-    trailing-REF retire."""
+    absent), same ``(stats, core_end, events)`` return followed by the
+    lane's admission attempts (``WindowState.attempts``), same
+    trailing-REF retire.  ``lane_axes`` names the vmap axes the caller
+    runs lanes over (none for one point)."""
     n_cores, L = trace["gap"].shape
     trace = dict(trace)
     if "next_same" not in trace:
@@ -295,12 +356,12 @@ def _run_window_impl(shape: SimShape, W: int, params: MechParams,
             shape.envelope.max_banks_total, fb, fr, trace["length"])
     ws = _init_window(shape, n_cores, L, W)
     step = _make_window_step(shape, W, params, trace, warmup_steps,
-                             collect_events)
+                             collect_events, lane_axes)
     ws, events = jax.lax.scan(step, ws,
                               jnp.arange(n_steps, dtype=jnp.int32))
     stats = sim_mod._retire_trailing_refs(ws.sim.stats, ws.sim.core_end,
                                           params)
-    return stats, ws.sim.core_end, events
+    return stats, ws.sim.core_end, events, ws.attempts
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 5, 6))
@@ -322,10 +383,12 @@ def _run_window_batched(shape: SimShape, W: int, params: MechParams,
     hoisted per-distinct-geometry ``next_same`` tables, optional
     on-device reduction — with the static window depth ``W`` shared by
     every point (in-order riders run with traced ``win_cap=1``)."""
+    lanes = ("point",)
     if ns_geoms is None:
         out = jax.vmap(
             lambda p: _run_window_impl(shape, W, p, trace, warmup_steps,
-                                       n_steps, collect_events))(params)
+                                       n_steps, collect_events, lanes),
+            axis_name="point")(params)
     else:
         ns = sim_mod._ns_tables(shape, trace, ns_geoms)
 
@@ -333,8 +396,8 @@ def _run_window_batched(shape: SimShape, W: int, params: MechParams,
             return _run_window_impl(shape, W, p,
                                     {**trace, "next_same": ns[gi]},
                                     warmup_steps, n_steps,
-                                    collect_events)
-        out = jax.vmap(one)(params, ns_idx)
+                                    collect_events, lanes)
+        out = jax.vmap(one, axis_name="point")(params, ns_idx)
     if reduce_keys is not None:
         return sim_mod._reduce_device(out[0], out[1], reduce_keys)
     return out
@@ -347,20 +410,22 @@ def _run_window_grid(shape: SimShape, W: int, params: MechParams,
                      ns_geoms: GeomParams | None = None, ns_idx=None,
                      reduce_keys: tuple | None = None):
     """Nested [batch, grid] window engine (``sweep_traces`` route)."""
+    lanes = ("trace", "point")
+
     def per_trace(trace, warmup):
         if ns_geoms is None:
             return jax.vmap(
                 lambda p: _run_window_impl(shape, W, p, trace, warmup,
-                                           n_steps,
-                                           collect_events))(params)
+                                           n_steps, collect_events, lanes),
+                axis_name="point")(params)
         ns = sim_mod._ns_tables(shape, trace, ns_geoms)
 
         def one(p, gi):
             return _run_window_impl(shape, W, p,
                                     {**trace, "next_same": ns[gi]},
-                                    warmup, n_steps, collect_events)
-        return jax.vmap(one)(params, ns_idx)
-    out = jax.vmap(per_trace)(traces, warmups)
+                                    warmup, n_steps, collect_events, lanes)
+        return jax.vmap(one, axis_name="point")(params, ns_idx)
+    out = jax.vmap(per_trace, axis_name="trace")(traces, warmups)
     if reduce_keys is not None:
         return sim_mod._reduce_device(out[0], out[1], reduce_keys)
     return out
@@ -380,8 +445,9 @@ def _run_window_synth_batched(shape: SimShape, W: int, n_cores: int,
     def one(p, wp, il, wu):
         trace = generate(n_cores, max_len, wp, p.geom, il)
         return _run_window_impl(shape, W, p, trace, wu, n_steps,
-                                collect_events)
-    out = jax.vmap(one)(params, wparams, ilparams, warmups)
+                                collect_events, ("point",))
+    out = jax.vmap(one, axis_name="point")(params, wparams, ilparams,
+                                           warmups)
     if reduce_keys is not None:
         return sim_mod._reduce_device(out[0], out[1], reduce_keys)
     return out

@@ -137,7 +137,10 @@ def _next_same_host(fb, fr, length):
 
 def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
     """Run the host oracle; returns ``{**STAT_KEYS, total_cycles,
-    core_end}`` with exact-int values matching ``simulate(batch, cfg)``.
+    core_end}`` with exact-int values matching ``simulate(batch, cfg)``,
+    and ``admit_attempts``: the admission tries of every step, the
+    failing one included, at most the window cap a step (for a
+    ``frfcfs`` point, what ``repro.obs.admit_attempts`` counts).
 
     Handles both tiers: ``cfg.controller == "inorder"`` runs the same
     decision loop with a window cap of 1 (the window engine's in-order
@@ -409,11 +412,12 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
         return done, t_act, needs_act
 
     serviced = 0
+    attempts = 0
     while serviced < n_req:
-        # admission: refill up to the cap (a failed attempt leaves the
-        # state unchanged, so breaking early == the engine's masked
-        # no-op fori_loop iterations)
+        # admission: refill up to the cap, stopping at the first failed
+        # attempt (it leaves the state unchanged), as the engine's loop
         for _ in range(cap):
+            attempts += 1
             if not try_admit():
                 break
         assert window, "window engine deadlock (oracle)"
@@ -460,4 +464,5 @@ def run_host(batch, cfg: SimConfig = SimConfig()) -> dict:
     out = dict(stats)
     out["core_end"] = np.asarray(core_end, np.int64)
     out["total_cycles"] = max(core_end)
+    out["admit_attempts"] = attempts
     return out

@@ -1221,8 +1221,10 @@ def simulate(batch: TraceBatch, cfg: SimConfig = SimConfig()) -> dict:
     if cfg.controller == "frfcfs":
         from repro.controller import engine as ctrl_engine
         _record_launch("_run_window", shape, n_steps)
-        raw_stats, core_end, events = ctrl_engine._run_window(
+        raw_stats, core_end, events, attempts = ctrl_engine._run_window(
             shape, cfg.window, mech_params(cfg), trace, warmup, n_steps)
+        _drain_admits([_AdmitTally(attempts, "_run_window", shape,
+                                   n_steps)])
     else:
         _record_launch("_run", shape, n_steps)
         raw_stats, core_end, events = _run(shape, mech_params(cfg), trace,
@@ -1260,6 +1262,36 @@ def _shard_grid(stacked: MechParams, n_grid: int):
 def _record_launch(engine: str, shape: SimShape, n_steps: int) -> None:
     """Count a dispatched launch's scan steps (``repro.obs.scan_steps``)."""
     obs.record_launch(engine, shape.envelope.max_bank_groups > 1, n_steps)
+
+
+class _AdmitTally(NamedTuple):
+    """A window-engine launch's per-lane admission attempts (device
+    array, not yet copied back) and what its drain files them under in
+    ``repro.obs.admit_attempts``."""
+    attempts: jax.Array
+    engine: str
+    shape: SimShape
+    steps: int
+
+
+def _tally_admits(out, engine: str, shape: SimShape, n_steps: int,
+                  reduce_keys: tuple | None):
+    """A window launch's output with its last element, the lanes'
+    admission attempts, wrapped for the drain; a reduced launch returns
+    its metric columns alone and passes through."""
+    if reduce_keys is not None:
+        return out
+    *head, attempts = out
+    return (*head, _AdmitTally(attempts, engine, shape, n_steps))
+
+
+def _drain_admits(tallies) -> None:
+    """File the admission attempts of a drained window launch (none for
+    the in-order engines, whose output has no tally)."""
+    for t in tallies:
+        a = np.asarray(t.attempts)
+        obs.record_admits(t.engine, t.shape.envelope.max_bank_groups > 1,
+                          a.sum(), a.size * t.steps)
 
 
 def _uniform_backend(grid: Sequence[SimConfig]) -> str:
@@ -1410,9 +1442,10 @@ def _launch_batch(shape, stacked, trace, warmup, n_steps: int,
         from repro.controller import engine as ctrl_engine
         (stacked, ns_idx), _ = _shard_grid((stacked, ns_idx), n_grid)
         _record_launch("_run_window_batched", shape, n_steps)
-        return ctrl_engine._run_window_batched(
+        return _tally_admits(ctrl_engine._run_window_batched(
             shape, window, stacked, trace, warmup, n_steps,
-            collect_events, ns_geoms, ns_idx, reduce_keys)
+            collect_events, ns_geoms, ns_idx, reduce_keys),
+            "_run_window_batched", shape, n_steps, reduce_keys)
     if backend == "pallas":
         from repro.kernels.sim_step import ops as sim_step_ops
         _record_launch("sim_step.run_sweep", shape, n_steps)
@@ -1436,10 +1469,11 @@ def _drain_batch(out, grid, lengths, n_grid: int,
     if reduce_keys is not None:
         with span("d2h"):
             return np.asarray(out)[:n_grid]
-    raw_stats, core_end, events = out
+    raw_stats, core_end, events, *tally = out
     with span("d2h"):
         stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
         core_np = np.asarray(core_end)
+        _drain_admits(tally)
     hist_np, total_np = _rltl_np(events, _rltl_edges(grid, events))
     with span("finalize"):
         return [
@@ -1518,9 +1552,10 @@ def _launch_grid(shape, stacked, traces, warmups, n_steps: int,
     if controller == "frfcfs":
         from repro.controller import engine as ctrl_engine
         _record_launch("_run_window_grid", shape, n_steps)
-        return ctrl_engine._run_window_grid(
+        return _tally_admits(ctrl_engine._run_window_grid(
             shape, window, stacked, traces, warmups, n_steps,
-            collect_events, ns_geoms, ns_idx, reduce_keys)
+            collect_events, ns_geoms, ns_idx, reduce_keys),
+            "_run_window_grid", shape, n_steps, reduce_keys)
     _record_launch("_run_grid", shape, n_steps)
     return _run_grid(shape, stacked, traces, warmups, n_steps,
                      collect_events, ns_geoms, ns_idx, reduce_keys)
@@ -1531,11 +1566,12 @@ def _drain_grid(out, grid, batches, n_batch: int,
     if reduce_keys is not None:
         with span("d2h"):
             return np.asarray(out)[:n_batch]
-    raw_stats, core_end, events = out
+    raw_stats, core_end, events, *tally = out
     with span("d2h"):
         stats_np = {k: np.asarray(v)
                     for k, v in raw_stats.items()}  # [B, G]
         core_np = np.asarray(core_end)
+        _drain_admits(tally)
     hist_np, total_np = _rltl_np(events, _rltl_edges(grid, events))
     rows = []
     for b in range(n_batch):
@@ -1762,9 +1798,10 @@ def _launch_synth(shape, n_cores: int, max_len: int, stacked, wstack,
         (stacked, wstack, ilstack, warmups), _ = _shard_grid(
             (stacked, wstack, ilstack, warmups), n_grid)
         _record_launch("_run_window_synth_batched", shape, n_steps)
-        return ctrl_engine._run_window_synth_batched(
+        return _tally_admits(ctrl_engine._run_window_synth_batched(
             shape, window, n_cores, max_len, stacked, wstack, ilstack,
-            warmups, n_steps, collect_events, reduce_keys)
+            warmups, n_steps, collect_events, reduce_keys),
+            "_run_window_synth_batched", shape, n_steps, reduce_keys)
     if backend == "pallas":
         from repro.kernels.sim_step import ops as sim_step_ops
         _record_launch("sim_step.run_synth", shape, n_steps)
@@ -1787,10 +1824,11 @@ def _drain_synth(out, grid, n_grid: int,
     if reduce_keys is not None:
         with span("d2h"):
             return np.asarray(out)[:n_grid]
-    raw_stats, core_end, events = out
+    raw_stats, core_end, events, *tally = out
     with span("d2h"):
         stats_np = {k: np.asarray(v) for k, v in raw_stats.items()}
         core_np = np.asarray(core_end)
+        _drain_admits(tally)
     hist_np, total_np = _rltl_np(events, _rltl_edges(grid, events))
     with span("finalize"):
         return [

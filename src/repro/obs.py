@@ -17,7 +17,11 @@ imported (``repro.core`` imports it before anything can compile).
 ``scan_steps()`` returns the scan steps the engine launches ran, by
 engine jit and by whether the bank-group path was compiled in: the
 launch functions record each launch's scan length on the host when
-they dispatch it, never per step.
+they dispatch it, never per step.  ``admit_attempts()`` returns, under
+the same keys, the admission attempts the window engine's lanes ran and
+their lane-steps: each lane counts its own attempts on the device, and
+the drain of a launch with full stats adds them up when it copies the
+stats back.
 
 This module is the only one in the program that touches
 ``jax.profiler`` or ``jax.monitoring``.
@@ -43,6 +47,7 @@ BANK_GROUPS, NO_BANK_GROUPS = "bank_groups", "no_bank_groups"
 _lock = threading.Lock()
 _counts: dict[tuple[str, str], list] = {}
 _steps: dict[tuple[str, str], int] = {}
+_admits: dict[tuple[str, str], list] = {}
 
 
 def span(name: str, **args):
@@ -75,12 +80,27 @@ def jit_cache() -> dict[str, dict[str, tuple[int, float]]]:
     return out
 
 
+def _path_key(engine: str, bank_groups: bool) -> tuple[str, str]:
+    return engine, BANK_GROUPS if bank_groups else NO_BANK_GROUPS
+
+
 def record_launch(engine: str, bank_groups: bool, steps: int) -> None:
     """Count one dispatched launch of ``engine`` (its jit's name) that
     scans ``steps`` steps, on the bank-group path or not."""
-    key = (engine, BANK_GROUPS if bank_groups else NO_BANK_GROUPS)
+    key = _path_key(engine, bank_groups)
     with _lock:
         _steps[key] = _steps.get(key, 0) + int(steps)
+
+
+def record_admits(engine: str, bank_groups: bool, attempts: int,
+                  lane_steps: int) -> None:
+    """Count ``attempts`` admission attempts over ``lane_steps`` scan
+    steps of the lanes of one drained window-engine launch."""
+    key = _path_key(engine, bank_groups)
+    with _lock:
+        entry = _admits.setdefault(key, [0, 0])
+        entry[0] += int(attempts)
+        entry[1] += int(lane_steps)
 
 
 def scan_steps() -> dict[tuple[str, str], int]:
@@ -90,6 +110,19 @@ def scan_steps() -> dict[tuple[str, str], int]:
     or ``"no_bank_groups"``."""
     with _lock:
         return dict(_steps)
+
+
+def admit_attempts() -> dict[tuple[str, str], tuple[int, int]]:
+    """``{(engine, path): (attempts, lane_steps)}`` since the process
+    started, keyed as ``scan_steps()``: the window engine's admission
+    attempts, the failing one that ends a step's loop included, and the
+    scan steps of every lane that ran them (padding lanes of a launch
+    count like the points they repeat).  ``attempts / lane_steps`` is
+    the mean trips of a lane's admission loop, at most the window depth
+    ``W``.  Launches that reduce on the device (``reduce=``) are not
+    counted."""
+    with _lock:
+        return {k: (a, n) for k, (a, n) in _admits.items()}
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)

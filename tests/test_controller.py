@@ -140,8 +140,8 @@ def test_rank_act_spacing_trrd_tfaw():
     trace = sim_mod._device_trace(batch)
     n_steps = int(batch.length.sum())
     p = mech_params(cfg)
-    _, _, events = ctrl_engine._run_window(sim_shape(cfg), cfg.window, p,
-                                           trace, 0, n_steps)
+    _, _, events, _ = ctrl_engine._run_window(
+        sim_shape(cfg), cfg.window, p, trace, 0, n_steps)
     gid = np.asarray(events.act_gid)
     t = np.asarray(events.act_t)[gid >= 0]
     bank = gid[gid >= 0] // dram.n_rows

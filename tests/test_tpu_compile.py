@@ -190,6 +190,39 @@ def test_run_window_batched_compiles(one_chip, trace_launch, window):
             shape, window, p, tr, wu, n_steps, True, g, gi)), *dyn, *ns)
 
 
+def test_window_admission_loop_copies_no_carry_array(one_chip):
+    """A [batch, grid] window launch at W=16 (2 eight-core traces x 2
+    points): the admission loop nested in the scan step must copy no
+    window-slot ``[2, 2, 16]`` or per-core ``[2, 2, 8]`` array on any
+    trip.  Its lanes loop while any one still admits and a finished lane
+    re-runs a failed attempt, a no-op; looping on each lane's own
+    predicate instead makes vmap select every carry leaf per lane, and
+    the compiler then copied them into another layout each trip (12
+    here, 19 in the ``frfcfs16.mix8`` launch)."""
+    grid = [_thesis_cfg(k, controller="frfcfs", window=16)
+            for k in ("base", "chargecache")]
+    shape, stacked = sim_mod._grid_shape_and_params(grid, grid)
+    ns_geoms, ns_idx = sim_mod._hoist_geoms(grid, grid)
+    batches = [multicore_batch(mix, 100, seed=7 + i)
+               for i, mix in enumerate(random_mixes(2, 8))]
+    traces = jax.tree_util.tree_map(
+        lambda *xs: np.stack(xs), *[sim_mod._device_trace(b) for b in batches])
+    n_steps = int(batches[0].gap.size)
+    dyn = _specs((stacked, traces, np.zeros(2, np.int32), ns_geoms, ns_idx),
+                 one_chip)
+    hlo = _compile(jax.jit(lambda p, tr, wu, g, gi: ctrl_engine._run_window_grid(
+        shape, 16, p, tr, wu, n_steps, False, g, gi)), *dyn).as_text()
+    bodies = _while_bodies(hlo)
+    nested = {inner for lines in bodies.values()
+              for inner in re.findall(r"body=%?([\w.\-]+)", "\n".join(lines))}
+    assert len(nested) == 1, f"expected one loop inside the scan: {nested}"
+    (admit,) = nested
+    copies = re.findall(r"= (?:s32|pred)\[([\d,]+)\]\S* copy\(",
+                        "\n".join(bodies[admit]))
+    carry = [c for c in copies if c in ("2,2,16", "2,2,8")]
+    assert not carry, f"{admit} copies carry arrays every trip: {carry}"
+
+
 def test_run_synth_batched_compiles(one_chip):
     spec = WorkloadSpec(names=tuple(random_mixes(1, 8)[0]), n_req=N_REQ,
                         seed=3)
